@@ -11,33 +11,28 @@ over a ``("data",)`` mesh of forced virtual host devices — and records
   * exactness: sharded ``block_size=1`` losses must match the existing
     single-device engine bitwise.
 
-This module forces ``--xla_force_host_platform_device_count=8`` BEFORE
-importing jax (like repro.launch.dryrun), so it must run in its own
-process: ``PYTHONPATH=src python -m benchmarks.async_scale [--full]``
-(``benchmarks.run --only async_scale`` spawns exactly that subprocess).
+A CPU-only command of its own: ``main()`` holds JAX to the CPU and
+forces ``--xla_force_host_platform_device_count=8`` before the backend
+starts, so run it as ``PYTHONPATH=src python -m benchmarks.async_scale
+[--full]``. Its times are CPU times; the same path on real chips is
+``chip_smoke.py --four-chips``.
 """
 from __future__ import annotations
 
+import argparse
 import os
+import time
 
-_FLAGS = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _FLAGS:
-    os.environ["XLA_FLAGS"] = (
-        _FLAGS + " --xla_force_host_platform_device_count=8").strip()
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import argparse     # noqa: E402
-import time         # noqa: E402
-
-import jax          # noqa: E402
-import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
-
-from repro.configs import VFLConfig                    # noqa: E402
-from repro.configs.paper_mlp import PaperMLPConfig     # noqa: E402
-from repro.core import async_engine                    # noqa: E402
-from repro.data import make_classification, vertical_partition  # noqa: E402
-from repro.launch.mesh import make_client_mesh         # noqa: E402
-from repro.models import common, tabular               # noqa: E402
+from repro.configs import VFLConfig
+from repro.configs.paper_mlp import PaperMLPConfig
+from repro.core import async_engine
+from repro.data import make_classification, vertical_partition
+from repro.launch.mesh import make_client_mesh
+from repro.models import common, tabular
 
 BLOCKS = (1, 4, 16)
 N_CLIENTS = 16      # divisible by every shard count we sweep
@@ -108,10 +103,21 @@ def bench_async_scale(fast: bool = True, row=None, blocks=BLOCKS):
     return results, exact, growths
 
 
+def _force_virtual_cpu_devices(n: int = 8) -> None:
+    """Hold JAX to ``n`` virtual CPU devices; must run before the backend
+    starts (the first device query)."""
+    flags = os.environ.get("XLA_FLAGS", "")
+    if "xla_force_host_platform_device_count" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}").strip()
+    jax.config.update("jax_platforms", "cpu")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", dest="fast", action="store_false", default=True)
     args = ap.parse_args()
+    _force_virtual_cpu_devices()
     print("name,us_per_call,derived")
     print(f"# devices={jax.device_count()}")
     _, exact, growths = bench_async_scale(args.fast)

@@ -10,8 +10,6 @@ Prints ``name,us_per_call,derived`` CSV rows (one per measured cell).
   bench_wire                — §II communication efficiency (bytes/round)
   bench_kernels             — kernel microbench (XLA-path oracle timing)
   bench_zoo_fanout          — stacked vs unrolled ZOO fan-out, q ∈ {1,4,16}
-  bench_async_scale         — device-sharded client block, block ∈ {1,4,16}
-                              (subprocess: forces 8 virtual host devices)
   bench_lm_async            — reduced transformer server under the async
                               engine via Federation, q ∈ {1,4} + DP point
   bench_serve_throughput    — fused split-serve engine: seed per-token
@@ -30,6 +28,11 @@ Prints ``name,us_per_call,derived`` CSV rows (one per measured cell).
 ``benchmarks.history``) instead of being overwritten.
 
 Run: PYTHONPATH=src python -m benchmarks.run [--only NAME] [--fast]
+
+Every bench runs in this one process; an exception in any of them ends
+the run with a non-zero exit. The device-sharded block-size sweep over 8
+virtual CPU devices is a separate CPU-only command,
+``python -m benchmarks.async_scale``.
 """
 from __future__ import annotations
 
@@ -41,6 +44,8 @@ import time
 
 import jax
 import jax.numpy as jnp
+
+from repro.launch.compile_cache import enable_compile_cache
 
 ROWS = []
 
@@ -215,27 +220,6 @@ def bench_zoo_fanout(fast: bool):
     bench(fast, row=row)
 
 
-# ================================================ sharded async block ======
-
-def bench_async_scale(fast: bool):
-    """Spawned as a subprocess: the sweep forces 8 virtual host devices
-    via XLA_FLAGS, which must be set before jax first initializes — this
-    process has already locked the real device topology."""
-    import subprocess
-    import sys
-    cmd = [sys.executable, "-m", "benchmarks.async_scale"]
-    if not fast:
-        cmd.append("--full")
-    proc = subprocess.run(cmd, capture_output=True, text=True)
-    for line in proc.stdout.splitlines():
-        if line.startswith("async_scale"):
-            name, us, derived = line.split(",", 2)
-            row(name, float(us), derived)
-    if proc.returncode:
-        row("async_scale_failed", 0.0,
-            f"rc={proc.returncode};stderr={proc.stderr.strip()[-200:]}")
-
-
 # ================================================== LM async engine ========
 
 def bench_lm_async(fast: bool):
@@ -297,7 +281,6 @@ BENCHES = {
     "wire": bench_wire,
     "kernels": bench_kernels,
     "zoo_fanout": bench_zoo_fanout,
-    "async_scale": bench_async_scale,
     "lm_async": bench_lm_async,
     "serve_throughput": bench_serve_throughput,
     "wire_faults": bench_wire_faults,
@@ -312,6 +295,7 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true", default=True)
     ap.add_argument("--full", dest="fast", action="store_false")
     args = ap.parse_args()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for name, fn in BENCHES.items():
         if args.only and name != args.only:

@@ -23,7 +23,7 @@ caller maps pytree paths to parties); ``grad_mark`` adds ``grad``;
 releases); ``wire_boundary`` records the crossing — payload kind,
 direction, shape and dtype read off the jaxpr, plus the incoming taint —
 and clears taint (whatever legally crossed is the sanctioned release).
-Sub-jaxprs (``pjit``/``scan``/``while``/``cond``/``custom_jvp_call``/…)
+Sub-jaxprs (``jit``/``scan``/``while``/``cond``/``custom_jvp_call``/…)
 are walked recursively, loop carries to a fixed point; an unknown
 higher-order primitive falls back to all-inputs-to-all-outputs, a sound
 overapproximation.
@@ -52,7 +52,7 @@ from typing import (Any, Callable, Dict, FrozenSet, List, Mapping, Optional,
                     Sequence, Tuple)
 
 import jax
-from jax import core as jax_core
+from jax.extend import core as jax_core
 
 from repro.analysis.findings import Finding
 
@@ -185,8 +185,8 @@ class _Analyzer:
             env[v] = t
 
     # -- structured higher-order primitives --------------------------------
-    def _h_pjit(self, eqn: Any, ins: List[Taint],
-                record: bool) -> List[Taint]:
+    def _h_jit(self, eqn: Any, ins: List[Taint],
+               record: bool) -> List[Taint]:
         inner, _ = _as_open(eqn.params["jaxpr"])
         return self.run(inner, ins, record)
 

@@ -37,14 +37,9 @@ from __future__ import annotations
 
 from typing import Any, Sequence, Tuple
 
-from jax.interpreters import ad, batching, mlir
-
-try:  # jax >= 0.4.27 exposes Primitive via jax.extend
-    from jax.extend.core import Primitive
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Primitive  # type: ignore[attr-defined,no-redef]
-
 import jax
+from jax.extend.core import Primitive
+from jax.interpreters import ad, batching, mlir
 
 # Payload kinds a wire_boundary mark may carry. "emb" and "loss" mirror
 # repro.wire.codec.DATA_TAGS (training-plane frames); "token" is the

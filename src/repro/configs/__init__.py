@@ -1,6 +1,8 @@
 """Config registry: ``get_config(arch_id)`` / ``--arch`` selection."""
 from __future__ import annotations
 
+import dataclasses
+
 from repro.configs import (
     deepseek_v3_671b,
     granite_20b,
@@ -56,6 +58,23 @@ def get_config(arch_id: str) -> ModelConfig:
         ) from None
 
 
+def driver_config(arch_id: str, *, use_reduced: bool = True,
+                  n_layers: int = 0) -> ModelConfig:
+    """The config a launch driver runs.
+
+    ``use_reduced`` takes the CPU smoke variant (:func:`reduced`);
+    otherwise every published width is kept. ``n_layers`` > 0 then
+    replaces the depth and nothing else."""
+    cfg = get_config(arch_id)
+    if use_reduced:
+        cfg = reduced(cfg)
+    if n_layers < 0:
+        raise ValueError(f"n_layers must be >= 0, got {n_layers}")
+    if n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=n_layers)
+    return cfg
+
+
 def get_shape(name: str) -> ShapeConfig:
     try:
         return INPUT_SHAPES[name]
@@ -73,6 +92,7 @@ __all__ = [
     "ShapeConfig",
     "TrainConfig",
     "VFLConfig",
+    "driver_config",
     "get_config",
     "get_shape",
     "list_archs",

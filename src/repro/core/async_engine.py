@@ -77,7 +77,6 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.analysis import marks, tags
@@ -123,6 +122,9 @@ class EngineResult:
     # channel: structurally safe wire, no formal guarantee)
     epsilon: float = math.inf
     delta: float = 0.0
+    # the server's (M, n, e) embedding table after the last round; on a
+    # mesh run its rows stay sharded over the mesh's devices
+    table: Optional[jax.Array] = None
 
 
 def make_schedule(key, steps: int, n_clients: int,
@@ -242,7 +244,8 @@ def _session_run(adapter: ModelAdapter, transport, vfl: VFLConfig,
                         mean_delay=float(jnp.mean(delays)),
                         wire_bytes=ledger.total_bytes,
                         transmits_gradients=ledger.transmits_gradients,
-                        ledger=ledger, epsilon=eps, delta=delta)
+                        ledger=ledger, epsilon=eps, delta=delta,
+                        table=table)
 
 
 # ------------------------------------------------------------------------
@@ -547,11 +550,11 @@ def _make_sharded_step(adapter: ModelAdapter, transport, vfl: VFLConfig,
         table_l = table_l.at[safe_m[:, None], idx[None, :]].set(c_fresh_all)
         return clients, server, table_l, h
 
-    sharded = shard_map(
-        shard_body, mesh,
+    sharded = jax.shard_map(
+        shard_body, mesh=mesh,
         in_specs=(P(), P(), table_spec, P(CLIENT_AXIS), P(), P(), P(), P()),
         out_specs=(P(), P(), table_spec, P()),
-        check_rep=False)
+        check_vma=False)
 
     def step(params, table, m_blk, idx, key, x_parts, y):
         clients, server, table, h = sharded(

@@ -291,6 +291,8 @@ def make_install_prog(adapter: ModelAdapter, seq_len: int):
     def install(caches_st, logits_st, t_st, gen_pos_st, rem_st,
                 keydata_st, gen_buf_st, dense_caches, logits, rows, slots,
                 t0s, rem0s, keydata_w, gen_rows, gen_pos0s):
+        w = slots.shape[0]      # prefill may pad the wave past w rows
+
         def one(st, dense, plan):
             if plan.pooled:
                 # pooled leaves are (layers, B, S, *tail) densely: scatter
@@ -298,14 +300,15 @@ def make_install_prog(adapter: ModelAdapter, seq_len: int):
                 n_pages, pg = st.shape[1], st.shape[2]
                 flat = st.reshape((st.shape[0], n_pages * pg)
                                   + st.shape[3:])
-                vals = dense[:, :, :rows.shape[1]]
+                vals = dense[:, :w, :rows.shape[1]]
                 flat = flat.at[:, rows].set(vals.astype(st.dtype))
                 return flat.reshape(st.shape)
             idx = (slice(None),) * plan.batch_axis + (slots,)
+            dense = jax.lax.slice_in_dim(dense, 0, w, axis=plan.batch_axis)
             return st.at[idx].set(dense.astype(st.dtype))
 
         caches_st = jax.tree.map(one, caches_st, dense_caches, plans)
-        return (caches_st, logits_st.at[slots].set(logits[:, None]),
+        return (caches_st, logits_st.at[slots].set(logits[:w, None]),
                 t_st.at[slots].set(t0s),
                 gen_pos_st.at[slots].set(gen_pos0s),
                 rem_st.at[slots].set(rem0s),
@@ -360,6 +363,9 @@ class ServeScheduler:
         self.embed_dim = embed_dim
         self.vocab_size = vocab_size
         self.max_batch = max_batch
+        # device slot rows: max_batch, padded to the serve plane's floor
+        # (rows past max_batch are never occupied and stay inactive)
+        self.n_rows = max(max_batch, serving.MIN_ROWS)
         self.temperature = float(temperature)
         self.max_queue = max_queue
         self.preempt = bool(preempt)
@@ -380,7 +386,7 @@ class ServeScheduler:
         self._slot_pages: List[Optional[np.ndarray]] = [None] * max_batch
         self._remaining = np.zeros(max_batch, np.int64)   # host mirror
         self._admitted_at = np.zeros(max_batch, np.int64)
-        self._tables = np.full((max_batch, self.pages_per_seq),
+        self._tables = np.full((self.n_rows, self.pages_per_seq),
                                paging.ZERO_PAGE, np.int32)
         self._tables_dev = None     # device mirror, rebuilt on mutation
         self._results: Dict[int, RequestResult] = {}
@@ -391,20 +397,21 @@ class ServeScheduler:
         dense_specs = adapter.cache_specs(1, seq_len)
         self._plans = paging.leaf_plans(dense_specs)
         paged_specs = paging.paged_specs(
-            dense_specs, n_slots=max_batch, n_pages=self.n_pages,
+            dense_specs, n_slots=self.n_rows, n_pages=self.n_pages,
             page_size=self.page_size)
         self._caches_st = jax.tree.map(
             lambda s: jnp.zeros(s.shape, jnp.dtype(s.dtype)), paged_specs,
             is_leaf=lambda x: hasattr(x, "logical"))
         self._logits_st = None      # (slots, 1, 1, vocab)
-        self._t_st = jnp.zeros(max_batch, jnp.int32)
-        self._gen_pos_st = jnp.zeros(max_batch, jnp.int32)
-        self._rem_st = jnp.zeros(max_batch, jnp.int32)
-        self._gen_buf_st = jnp.zeros((max_batch, seq_len), jnp.int32)
+        self._t_st = jnp.zeros(self.n_rows, jnp.int32)
+        self._gen_pos_st = jnp.zeros(self.n_rows, jnp.int32)
+        self._rem_st = jnp.zeros(self.n_rows, jnp.int32)
+        self._gen_buf_st = jnp.zeros((self.n_rows, seq_len), jnp.int32)
         kd = jax.random.key_data(jax.random.key(0))
-        self._keydata_st = jnp.zeros((max_batch,) + kd.shape, kd.dtype)
+        self._keydata_st = jnp.zeros((self.n_rows,) + kd.shape, kd.dtype)
 
-        # persistent dense (1, seq_len) prefill buffer — only its small
+        # persistent dense (MIN_ROWS, seq_len) prefill buffer for width-1
+        # waves (the request's row and copies of it) — only its small
         # recurrent-state leaves are re-zeroed per admission
         self._prefill_caches = None
         # hot-loop executables keyed on the block length — the
@@ -497,19 +504,21 @@ class ServeScheduler:
         tests/test_serving_engine.py (wave admission at sampling
         temperature, where low-bit drift is visible)."""
         w = len(reqs)
+        rows = max(w, serving.MIN_ROWS)
         prompt_len = reqs[0].prompt.size
         if w == 1:
             if self._prefill_caches is None:
                 self._prefill_caches = serving.zero_caches(
-                    self.adapter, 1, self.seq_len)
+                    self.adapter, rows, self.seq_len)
             else:
                 self._prefill_caches = jax.tree.map(
                     lambda a, plan: a if plan.pooled else jnp.zeros_like(a),
                     self._prefill_caches, self._plans)
             caches = self._prefill_caches
         else:
-            caches = serving.zero_caches(self.adapter, w, self.seq_len)
-        toks = jnp.asarray(np.stack([r.prompt for r in reqs]), jnp.int32)
+            caches = serving.zero_caches(self.adapter, rows, self.seq_len)
+        toks = serving.pad_rows(
+            jnp.asarray(np.stack([r.prompt for r in reqs]), jnp.int32), rows)
         logits = None
         if self.adapter.server_prefill is not None:
             chunk_fn = serving.make_prefill_chunk(self.adapter,
@@ -547,13 +556,14 @@ class ServeScheduler:
         step = serving.make_serve_step(self.adapter, self.n_clients,
                                        self.seq_len)
         pl = req.prompt.size
-        tok0 = np.asarray([[req.generated[0]]], np.int32)
+        rows = serving.MIN_ROWS        # the width-1 wave's padded buffer
+        tok0 = np.full((rows, 1), req.generated[0], np.int32)
         prog, dt = serving.compiled_with_timing(
             step, self.params, tok0, caches, pl)
         self.compile_s += dt
         for i, tok in enumerate(np.asarray(req.generated, np.int32)):
             logits, caches = prog(self.params,
-                                  np.asarray([[tok]], np.int32),
+                                  np.full((rows, 1), tok, np.int32),
                                   caches, pl + i)
         return logits, caches
 
@@ -579,7 +589,7 @@ class ServeScheduler:
                 self._prefill_caches = caches
         if self._logits_st is None:
             self._logits_st = jnp.zeros(
-                (self.max_batch, 1) + logits.shape[1:], logits.dtype)
+                (self.n_rows, 1) + logits.shape[1:], logits.dtype)
 
         rows = jnp.asarray(np.stack([
             paging.install_rows(p, eff_len, self.page_size)
@@ -725,7 +735,7 @@ class ServeScheduler:
             block_fn = make_paged_decode_block(
                 self.adapter, self.n_clients, self.seq_len,
                 self.temperature, self.vocab_size, self.page_size,
-                self.max_batch, k)
+                self.n_rows, k)
             prog, dt = serving.compiled_with_timing(block_fn, *args)
             self.compile_s += dt
             self._block_progs[k] = prog

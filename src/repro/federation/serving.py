@@ -157,6 +157,17 @@ def _sig(args) -> Tuple:
     return tuple(out)
 
 
+# XLA for the TPU rewrites a dot with few lhs rows (a small decode step)
+# as a multiply and a reduction on the vector unit, while a larger batch
+# runs the same dot on the MXU. The two sum in different orders, so a
+# request's greedy tokens would depend on how many requests share its
+# step, and on a TPU v5e at phi3-mini widths the paged decode block and
+# the dense decode scan left each other after 13 tokens. Every
+# serve-plane program keeps its dots on the MXU instead.
+_BATCH_INVARIANT_OPTIONS = {
+    "tpu": {"xla_tpu_enable_dot_strength_reduction": False}}
+
+
 def compiled_with_timing(jitted, *args):
     """(compiled_executable, compile_seconds) — 0.0 on a cache hit."""
     key = (jitted, _sig(args))
@@ -165,7 +176,8 @@ def compiled_with_timing(jitted, *args):
         _AOT_CACHE[key] = hit          # refresh recency: dict order is the
         return hit, 0.0                # LRU list, eviction takes the front
     t0 = time.perf_counter()
-    compiled = jitted.lower(*args).compile()
+    compiled = jitted.lower(*args).compile(
+        _BATCH_INVARIANT_OPTIONS.get(jax.default_backend()))
     dt = time.perf_counter() - t0
     while len(_AOT_CACHE) >= _AOT_CACHE_MAX:
         del _AOT_CACHE[next(iter(_AOT_CACHE))]
@@ -299,6 +311,20 @@ def prefill_plan(prompt_len: int, span: int) -> List[Tuple[int, int, int]]:
     return plan
 
 
+# The fewest batch rows a serve-plane program runs. On a TPU v5e a row's
+# prefill and decode-step logits were bitwise the same at 4 and 8 rows,
+# but not at 1 or 2: XLA drops a unit batch dimension, and tiles a small
+# one otherwise, and either then reduces in another order. Smaller
+# batches are padded with copies of their first row, so one request
+# decodes the same tokens alone and batched.
+MIN_ROWS = 4
+
+
+def pad_rows(x, rows: int):
+    """``x`` with copies of its first row appended up to ``rows`` rows."""
+    return jnp.concatenate([x, jnp.repeat(x[:1], rows - x.shape[0], 0)])
+
+
 def zero_caches(adapter: ModelAdapter, batch: int, max_seq: int):
     return jax.tree.map(
         lambda s: jnp.zeros(s.shape, jnp.dtype(s.dtype)),
@@ -338,6 +364,7 @@ def run_decode(adapter: ModelAdapter, transport, *, n_clients: int,
     sampled tokens on device and transfers once at the end)."""
     prompts = jnp.asarray(prompts, jnp.int32)
     B, prompt_len = prompts.shape
+    prompts = pad_rows(prompts, max(B, MIN_ROWS))
     max_seq = prompt_len + gen_len
     if max_seq > seq_len:
         raise ValueError(
@@ -347,7 +374,7 @@ def run_decode(adapter: ModelAdapter, transport, *, n_clients: int,
         key = jax.random.key(0)
     span = seq_len // n_clients
     step = make_serve_step(adapter, n_clients, seq_len)
-    caches = zero_caches(adapter, B, max_seq)
+    caches = zero_caches(adapter, prompts.shape[0], max_seq)
     compile_s = 0.0
     chunked = chunked_prefill and adapter.server_prefill is not None
 
@@ -407,6 +434,6 @@ def run_decode(adapter: ModelAdapter, transport, *, n_clients: int,
     ledger = transport.account_serve(batch=B, embed=embed_dim,
                                      n_steps=max_seq, n_gen=gen_len,
                                      ledger=ledger)
-    return ServeResult(tokens=out_tokens, logits=logits,
+    return ServeResult(tokens=out_tokens[:B], logits=logits[:B],
                        ledger=ledger, prefill_s=prefill_s,
                        decode_s=decode_s, compile_s=compile_s)

@@ -2,7 +2,8 @@
 
 Each kernel package ships:
 * ``kernel.py`` — pl.pallas_call with explicit BlockSpec VMEM tiling
-* ``ops.py``    — jit'd public wrapper (interpret=True on CPU)
+* ``ops.py``    — jit'd public wrapper (compiled on TPU, interpreted on CPU;
+  see ``backend.interpret_mode``)
 * ``ref.py``    — pure-jnp oracle used by the allclose test sweeps
 """
 from repro.kernels.flash_attention.ops import flash_attention

@@ -1,7 +1,6 @@
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import interpret_mode
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 
 
@@ -10,4 +9,4 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     """Flash attention over (BH, S, d) tensors (heads pre-flattened)."""
     return flash_attention_pallas(
         q, k, v, causal=causal, window=window, bq=bq, bk=bk,
-        interpret=jax.default_backend() != "tpu")
+        interpret=interpret_mode())

@@ -1,7 +1,6 @@
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import interpret_mode
 from repro.kernels.ssd_chunk.kernel import ssd_chunk_pallas
 
 
@@ -9,4 +8,4 @@ def ssd_chunk(xh, a, dt, bm, cm, *, chunk: int = 128):
     """Mamba2 SSD over (BH, S, ·) tensors (batch·heads pre-flattened;
     B/C broadcast over heads by the caller)."""
     return ssd_chunk_pallas(xh, a, dt, bm, cm, chunk=chunk,
-                            interpret=jax.default_backend() != "tpu")
+                            interpret=interpret_mode())

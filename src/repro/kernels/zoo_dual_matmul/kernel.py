@@ -8,18 +8,37 @@ models this halves the bytes moved (x read once, and ŷ's extra work is one
 fused multiply-add on tiles already resident in VMEM).
 
 Tiling: grid over (M/bm, N/bn); each program reads the full-K stripes
-x (bm, K), W/U (K, bn) — for the assigned configs K = d_model ≤ 7168 so the
-working set (bm·K + 2·K·bn + 2·bm·bn at bf16) stays well under VMEM, and
-bm/bn are 128-multiples for the MXU.
+x (bm, K), W/U (K, bn), and bm/bn are 128-multiples for the MXU. The
+pipeline double-buffers every block, so the working set is about
+2·(bm·K + 2·K·bn + 2·bm·bn) elements: at K = 7168 in f32 that is ~22 MB,
+above the 16 MB of VMEM a TPU v5e program may use by default. Each call
+therefore asks the compiler for the scoped VMEM its blocks need
+(:func:`_vmem_limit`). The scalar μ lives in SMEM.
 """
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+# the scoped-VMEM limit a TPU v5e program gets unless it asks for more
+_DEFAULT_SCOPED_VMEM = 16 * 2 ** 20
+_SMEM = pl.BlockSpec(memory_space=pltpu.SMEM)
+
+
+def _vmem_limit(dtype, *blocks) -> pltpu.CompilerParams:
+    """Compiler params whose scoped-VMEM limit covers the pipelined
+    ``blocks`` (shapes in elements of ``dtype``), each double-buffered,
+    plus the (bm, bn) f32 accumulator and half again as headroom."""
+    size = jnp.dtype(dtype).itemsize
+    need = sum(2 * size * math.prod(b) for b in blocks)
+    need += 4 * math.prod(blocks[-1])
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=max(_DEFAULT_SCOPED_VMEM, need * 3 // 2))
 
 
 def _dual_matmul_kernel(x_ref, w_ref, u_ref, mu_ref, y_ref, y_hat_ref):
@@ -53,7 +72,7 @@ def zoo_dual_matmul_pallas(x, w, u, mu, *, bm: int = 128, bn: int = 128,
             pl.BlockSpec((bm, K), lambda i, j: (i, 0)),
             pl.BlockSpec((K, bn), lambda i, j: (0, j)),
             pl.BlockSpec((K, bn), lambda i, j: (0, j)),
-            pl.BlockSpec((1,), lambda i, j: (0,)),
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
@@ -63,6 +82,8 @@ def zoo_dual_matmul_pallas(x, w, u, mu, *, bm: int = 128, bn: int = 128,
             jax.ShapeDtypeStruct((M, N), x.dtype),
             jax.ShapeDtypeStruct((M, N), x.dtype),
         ],
+        compiler_params=_vmem_limit(x.dtype, (bm, K), (K, bn), (K, bn),
+                                    (bm, bn), (bm, bn)),
         interpret=interpret,
     )(x, w, u, mu_arr)
 
@@ -113,7 +134,7 @@ def _dual_matmul_stacked_bias_relu_kernel(x_ref, w_ref, u_ref, b_ref,
     yu = jnp.dot(x, u_ref[0], preferred_element_type=jnp.float32)
     mu = mu_ref[0]
     # lane l pre-activation: x(W + μU_l) + (b + μu_b_l)
-    pre = acc_ref[...] + mu * yu + (b + mu * ub_ref[0])
+    pre = acc_ref[...] + mu * yu + (b + mu * ub_ref[0, 0])
     y_hat_ref[0] = jnp.maximum(pre, 0.0).astype(y_hat_ref.dtype)
 
 
@@ -134,7 +155,9 @@ def zoo_dual_matmul_stacked_bias_relu_pallas(x, w, us, b, ub, mu, *,
     assert M % bm == 0 and N % bn == 0, (M, N, bm, bn)
     mu_arr = jnp.asarray([mu], jnp.float32)
     b2 = b.astype(jnp.float32)[None]                      # (1, N)
-    ub2 = ub.astype(jnp.float32)                          # (q, N)
+    # (q, 1, N): a lane's (1, bn) block then spans the whole second-minor
+    # dim, which the TPU tiling accepts; a (1, bn) block of (q, N) it refuses
+    ub2 = ub.astype(jnp.float32)[:, None]                 # (q, 1, N)
 
     grid = (M // bm, N // bn, q)
     return pl.pallas_call(
@@ -145,8 +168,8 @@ def zoo_dual_matmul_stacked_bias_relu_pallas(x, w, us, b, ub, mu, *,
             pl.BlockSpec((K, bn), lambda i, j, l: (0, j)),
             pl.BlockSpec((1, K, bn), lambda i, j, l: (l, 0, j)),
             pl.BlockSpec((1, bn), lambda i, j, l: (0, j)),
-            pl.BlockSpec((1, bn), lambda i, j, l: (l, j)),
-            pl.BlockSpec((1,), lambda i, j, l: (0,)),
+            pl.BlockSpec((1, 1, bn), lambda i, j, l: (l, 0, j)),
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
@@ -157,6 +180,8 @@ def zoo_dual_matmul_stacked_bias_relu_pallas(x, w, us, b, ub, mu, *,
             jax.ShapeDtypeStruct((q, M, N), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=_vmem_limit(x.dtype, (bm, K), (K, bn), (K, bn),
+                                    (bm, bn), (bm, bn)),
         interpret=interpret,
     )(x, w, us, b2, ub2, mu_arr)
 
@@ -183,7 +208,7 @@ def zoo_dual_matmul_stacked_pallas(x, w, us, mu, *, bm: int = 128,
             pl.BlockSpec((bm, K), lambda i, j, l: (i, 0)),
             pl.BlockSpec((K, bn), lambda i, j, l: (0, j)),
             pl.BlockSpec((1, K, bn), lambda i, j, l: (l, 0, j)),
-            pl.BlockSpec((1,), lambda i, j, l: (0,)),
+            _SMEM,
         ],
         out_specs=[
             pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
@@ -194,5 +219,7 @@ def zoo_dual_matmul_stacked_pallas(x, w, us, mu, *, bm: int = 128,
             jax.ShapeDtypeStruct((q, M, N), x.dtype),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
+        compiler_params=_vmem_limit(x.dtype, (bm, K), (K, bn), (K, bn),
+                                    (bm, bn), (bm, bn)),
         interpret=interpret,
     )(x, w, us, mu_arr)

@@ -1,21 +1,16 @@
-"""Public wrapper: pallas on TPU, interpret-mode pallas elsewhere."""
+"""Public wrapper: compiled Pallas on the TPU, interpreted on the CPU."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels.backend import interpret_mode
 from repro.kernels.zoo_dual_matmul.kernel import (
     zoo_dual_matmul_pallas, zoo_dual_matmul_stacked_bias_relu_pallas,
     zoo_dual_matmul_stacked_pallas)
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 def zoo_dual_matmul(x, w, u, mu, *, bm: int = 128, bn: int = 128):
     """y = x @ w ; y_hat = x @ (w + mu*u) — one fused pass."""
     return zoo_dual_matmul_pallas(x, w, u, mu, bm=bm, bn=bn,
-                                  interpret=not _on_tpu())
+                                  interpret=interpret_mode())
 
 
 def zoo_dual_matmul_stacked(x, w, us, mu, *, b=None, ub=None,
@@ -32,6 +27,6 @@ def zoo_dual_matmul_stacked(x, w, us, mu, *, b=None, ub=None,
                          "or neither")
     if b is not None:
         return zoo_dual_matmul_stacked_bias_relu_pallas(
-            x, w, us, b, ub, mu, bm=bm, bn=bn, interpret=not _on_tpu())
+            x, w, us, b, ub, mu, bm=bm, bn=bn, interpret=interpret_mode())
     return zoo_dual_matmul_stacked_pallas(x, w, us, mu, bm=bm, bn=bn,
-                                          interpret=not _on_tpu())
+                                          interpret=interpret_mode())

@@ -4,11 +4,6 @@ Defined as FUNCTIONS (not module constants) so importing this module never
 touches jax device state — the dry-run sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before first jax
 init; smoke tests and benches see the real single device.
-
-``jax.sharding.AxisType`` (and ``jax.make_mesh``'s ``axis_types`` kwarg)
-only exist on newer JAX releases; :func:`_compat_make_mesh` feature-detects
-them and falls back to the plain ``make_mesh`` signature so the same code
-runs on the pinned JAX.
 """
 from __future__ import annotations
 
@@ -18,28 +13,21 @@ import jax
 import numpy as np
 
 
-def _compat_make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """make_mesh with Auto axis types where supported, plain mesh otherwise."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return jax.make_mesh(shape, axes,
-                                 axis_types=(axis_type.Auto,) * len(axes))
-        except TypeError:
-            pass  # make_mesh predates the axis_types kwarg
-    return jax.make_mesh(shape, axes)
+def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     """16×16 = 256 chips per pod (v5e); 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return _compat_make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh() -> jax.sharding.Mesh:
     """Single-device mesh for CPU smoke runs (axes exist, size 1)."""
-    return _compat_make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))
 
 
 def make_client_mesh(n_shards: Optional[int] = None) -> jax.sharding.Mesh:

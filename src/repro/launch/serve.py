@@ -15,10 +15,13 @@ the same step functions are what the dry-run lowers for ``decode_32k`` /
 
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-7b --reduced \
         --batch 4 --prompt-len 16 --gen-len 16 [--clients 2]
+    PYTHONPATH=src python -m repro.launch.serve --full --layers 8 \
+        --continuous --batch 8 --prompt-len 128 --gen-len 32  # one TPU v5e
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import json
 import time
 
@@ -26,7 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import get_config, list_archs, reduced
+from repro.configs import driver_config, list_archs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import common
 from repro.models.model_api import build_cache_specs, build_model
 
@@ -42,9 +46,18 @@ def _splittable(cfg) -> bool:
     return not (cfg.is_encoder_decoder or cfg.family == "vlm")
 
 
+def serve_config(arch: str, *, use_reduced: bool = True,
+                 n_layers: int = 0):
+    """The config :func:`serve` runs: the driver's config without remat
+    (decode has no backward pass to recompute for)."""
+    return dataclasses.replace(
+        driver_config(arch, use_reduced=use_reduced, n_layers=n_layers),
+        remat=False)
+
+
 def serve(arch: str, *, batch: int = 4, prompt_len: int = 16,
-          gen_len: int = 16, use_reduced: bool = True, seed: int = 0,
-          temperature: float = 0.0, n_clients: int = 0,
+          gen_len: int = 16, use_reduced: bool = True, n_layers: int = 0,
+          seed: int = 0, temperature: float = 0.0, n_clients: int = 0,
           continuous: bool = False, max_batch: int = 4,
           max_queue: int = None, preempt: bool = False,
           n_pages: int = None, deadline: int = None) -> dict:
@@ -58,29 +71,36 @@ def serve(arch: str, *, batch: int = 4, prompt_len: int = 16,
     admission (the driver drains on :class:`QueueFull` and retries),
     ``preempt``/``n_pages`` enable page-pool preemption under memory
     pressure, and ``deadline`` gives every request that many scheduler
-    steps to retire (expired requests come back ``status="deadline"``)."""
-    cfg = get_config(arch)
-    if use_reduced:
-        cfg = reduced(cfg, remat=False)
+    steps to retire (expired requests come back ``status="deadline"``).
+    ``use_reduced=False`` keeps the published widths; ``n_layers`` > 0
+    replaces the depth alone."""
+    cfg = serve_config(arch, use_reduced=use_reduced, n_layers=n_layers)
     if n_clients and _splittable(cfg):
         if continuous:
-            return _serve_continuous(arch, cfg, batch=batch,
-                                     prompt_len=prompt_len,
-                                     gen_len=gen_len, seed=seed,
-                                     temperature=temperature,
-                                     n_clients=n_clients,
-                                     max_batch=max_batch,
-                                     max_queue=max_queue, preempt=preempt,
-                                     n_pages=n_pages, deadline=deadline)
-        return _serve_federated(arch, cfg, batch=batch,
-                                prompt_len=prompt_len, gen_len=gen_len,
-                                seed=seed, temperature=temperature,
-                                n_clients=n_clients)
-    res = _serve_global(arch, cfg, batch=batch, prompt_len=prompt_len,
-                        gen_len=gen_len, seed=seed, temperature=temperature)
-    if n_clients:
-        res["fallback"] = (f"{cfg.family}/encdec family needs a modality "
-                           "frontend on the wire; served global")
+            res = _serve_continuous(arch, cfg, batch=batch,
+                                    prompt_len=prompt_len, gen_len=gen_len,
+                                    seed=seed, temperature=temperature,
+                                    n_clients=n_clients,
+                                    max_batch=max_batch,
+                                    max_queue=max_queue, preempt=preempt,
+                                    n_pages=n_pages, deadline=deadline)
+        else:
+            res = _serve_federated(arch, cfg, batch=batch,
+                                   prompt_len=prompt_len, gen_len=gen_len,
+                                   seed=seed, temperature=temperature,
+                                   n_clients=n_clients)
+    else:
+        res = _serve_global(arch, cfg, batch=batch, prompt_len=prompt_len,
+                            gen_len=gen_len, seed=seed,
+                            temperature=temperature)
+        if n_clients:
+            res["fallback"] = (f"{cfg.family}/encdec family needs a "
+                               "modality frontend on the wire; served "
+                               "global")
+    # the depth and widths that actually ran
+    res["model"] = {k: getattr(cfg, k) for k in (
+        "n_layers", "d_model", "n_heads", "n_kv_heads", "d_ff",
+        "vocab_size")}
     return res
 
 
@@ -97,6 +117,16 @@ def _build_session(cfg, *, n_clients: int, prompt_len: int, gen_len: int,
     key = jax.random.key(seed)
     params = common.materialize(fed.model.param_specs, key)
     return fed, key, params
+
+
+def request_prompts(key, batch: int, prompt_len: int, vocab_size: int):
+    """The continuous path's prompts, (batch, prompt_len) int32 on the
+    host: request i draws from ``fold_in(key, 1000 + i)``. All are drawn
+    in one batched device op and fetched with a single transfer."""
+    return np.asarray(jax.vmap(
+        lambda i: jax.random.randint(jax.random.fold_in(key, 1000 + i),
+                                     (prompt_len,), 0, vocab_size))(
+                                         jnp.arange(batch)))
 
 
 def _serve_federated(arch: str, cfg, *, batch: int, prompt_len: int,
@@ -139,13 +169,7 @@ def _serve_continuous(arch: str, cfg, *, batch: int, prompt_len: int,
                                       gen_len=gen_len, seed=seed)
     srv = fed.serve(params, max_batch=max_batch, temperature=temperature,
                     max_queue=max_queue, preempt=preempt, n_pages=n_pages)
-    # draw every request's prompt in one batched device op and fetch the
-    # whole (batch, prompt_len) block with a single transfer — same
-    # per-request fold_in streams as drawing them one by one
-    prompts = np.asarray(jax.vmap(
-        lambda i: jax.random.randint(jax.random.fold_in(key, 1000 + i),
-                                     (prompt_len,), 0, cfg.vocab_size))(
-                                         jnp.arange(batch)))
+    prompts = request_prompts(key, batch, prompt_len, cfg.vocab_size)
     queue_retries = 0
     for i in range(batch):
         while True:
@@ -182,6 +206,8 @@ def _serve_continuous(arch: str, cfg, *, batch: int, prompt_len: int,
         "wire_bytes": sum(r.wire_bytes for r in results),
         "wire_has_gradients": any(r.transmits_gradients for r in results),
         "sample_output": (ok[0] if ok else results[0]).tokens[:8].tolist(),
+        # every request's generated ids, in rid order
+        "tokens": [r.tokens.tolist() for r in results],
     }
 
 
@@ -254,6 +280,10 @@ def main():
     ap.add_argument("--gen-len", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--reduced", action="store_true", default=True)
+    # published widths (no reduced()); pair with --layers to fit a chip
+    ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="replace the config's depth only (0 = keep it)")
     # 0 = the pre-session global path; >=1 serves split via fed.decode
     ap.add_argument("--clients", type=int, default=2)
     # continuous batching: drain --batch requests through --max-batch slots
@@ -266,10 +296,12 @@ def main():
     ap.add_argument("--n-pages", type=int, default=None)
     ap.add_argument("--deadline", type=int, default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     print(json.dumps(serve(args.arch, batch=args.batch,
                            prompt_len=args.prompt_len, gen_len=args.gen_len,
                            temperature=args.temperature,
                            use_reduced=args.reduced,
+                           n_layers=args.layers,
                            n_clients=args.clients,
                            continuous=args.continuous,
                            max_batch=args.max_batch,

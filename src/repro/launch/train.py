@@ -25,6 +25,8 @@ re-stretching, running at the tail lr past the original horizon).
 
     PYTHONPATH=src python -m repro.launch.train --arch phi3-mini-3.8b \
         --reduced --steps 100 --method cascaded [--dp-epsilon 1.0]
+    PYTHONPATH=src python -m repro.launch.train --full --layers 8 \
+        --batch 8 --seq 512 --zoo-queries 4 --steps 5   # one TPU v5e
     PYTHONPATH=src python -m repro.launch.train --resume ck/ --steps 200 \
         --checkpoint ck2/
 """
@@ -41,12 +43,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.configs import VFLConfig, get_config, list_archs, reduced
+from repro.configs import VFLConfig, driver_config, list_archs
 from repro.core.async_engine import EngineConfig, PopulationConfig
 from repro.core.methods import METHOD_ALIASES, canonical_method
 from repro.core.privacy import GaussianLossChannel
 from repro.data import lm_token_batches, vertical_partition
 from repro.federation import Federation, SessionState
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import common
 from repro.optim import make_schedule, sgd
@@ -57,7 +60,7 @@ from repro.wire import FaultPlan
 def train(arch: str = "", *, steps: int = 100, batch: int = 8,
           seq: int = 128, method: str = "cascaded", lr: float = 0.01,
           mu: float = 1e-3, lr_client: float = 0.0,
-          use_reduced: bool = True, seed: int = 0,
+          use_reduced: bool = True, n_layers: int = 0, seed: int = 0,
           log_every: int = 10, zoo_queries: int = 1,
           active_rows: bool = False, production_mesh: bool = False,
           checkpoint_path: str = "", schedule: str = "constant",
@@ -91,9 +94,7 @@ def train(arch: str = "", *, steps: int = 100, batch: int = 8,
                 f"--steps {steps} is a total step count; the resumed "
                 f"session is already at step {start}")
     else:
-        cfg = get_config(arch)
-        if use_reduced:
-            cfg = reduced(cfg)
+        cfg = driver_config(arch, use_reduced=use_reduced, n_layers=n_layers)
         method = canonical_method(method)
         vfl = VFLConfig(mu=mu, lr_server=lr, lr_client=lr_client or lr,
                         zoo_queries=zoo_queries, active_rows_only=active_rows)
@@ -197,8 +198,9 @@ def train_population(arch: str = "", *, steps: int = 60, batch: int = 8,
                      seq: int = 32, method: str = "cascaded",
                      n_clients: int = 4, rows: int = 128, lr: float = 0.05,
                      mu: float = 1e-3, lr_client: float = 0.0,
-                     use_reduced: bool = True, seed: int = 0,
-                     zoo_queries: int = 1, fault_drop: float = 0.0,
+                     use_reduced: bool = True, n_layers: int = 0,
+                     seed: int = 0, zoo_queries: int = 1,
+                     fault_drop: float = 0.0,
                      fault_latency_ms: float = 0.0,
                      fault_jitter_ms: float = 0.0, fault_seed: int = 0,
                      admission_ms: Optional[float] = None,
@@ -233,9 +235,7 @@ def train_population(arch: str = "", *, steps: int = 60, batch: int = 8,
                       if meta.get("population") else None)
         noise = fed.transport.noise
     else:
-        cfg = get_config(arch)
-        if use_reduced:
-            cfg = reduced(cfg)
+        cfg = driver_config(arch, use_reduced=use_reduced, n_layers=n_layers)
         method = canonical_method(method)
         vfl = VFLConfig(mu=mu, lr_server=lr, lr_client=lr_client,
                         zoo_queries=zoo_queries)
@@ -352,7 +352,10 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--zoo-queries", type=int, default=1)
     ap.add_argument("--active-rows", action="store_true")
     ap.add_argument("--reduced", action="store_true", default=True)
+    # published widths (no reduced()); pair with --layers to fit a chip
     ap.add_argument("--full", dest="reduced", action="store_false")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="replace the config's depth only (0 = keep it)")
     ap.add_argument("--production-mesh", action="store_true")
     ap.add_argument("--checkpoint", default="")
     # continue a saved session; --steps then means TOTAL steps (the run
@@ -392,6 +395,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main():
     args = build_parser().parse_args()
+    enable_compile_cache()
     noise = (GaussianLossChannel(clip=args.dp_clip, epsilon=args.dp_epsilon,
                                  delta=args.dp_delta)
              if args.dp_epsilon > 0 else None)
@@ -400,8 +404,8 @@ def main():
             args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
             method=canonical_method(args.method), n_clients=args.clients,
             rows=args.rows, lr=args.lr, mu=args.mu, seed=args.seed,
-            use_reduced=args.reduced, zoo_queries=args.zoo_queries,
-            fault_drop=args.fault_drop,
+            use_reduced=args.reduced, n_layers=args.layers,
+            zoo_queries=args.zoo_queries, fault_drop=args.fault_drop,
             fault_latency_ms=args.fault_latency_ms,
             fault_jitter_ms=args.fault_jitter_ms,
             fault_seed=args.fault_seed,
@@ -413,7 +417,8 @@ def main():
         res = train(args.arch, steps=args.steps, batch=args.batch,
                     seq=args.seq, method=canonical_method(args.method),
                     lr=args.lr, mu=args.mu, use_reduced=args.reduced,
-                    seed=args.seed, zoo_queries=args.zoo_queries,
+                    n_layers=args.layers, seed=args.seed,
+                    zoo_queries=args.zoo_queries,
                     active_rows=args.active_rows,
                     production_mesh=args.production_mesh,
                     checkpoint_path=args.checkpoint,

@@ -4,11 +4,18 @@ Exercises the public API the way a user would: build a model from the
 registry, train it with the cascaded VFL driver, serve it, and check the
 paper's qualitative claims (cascaded ≈ FOO ≫ full-ZOO; no gradients on
 the wire)."""
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
 from repro.launch.serve import serve
 from repro.launch.train import train
+
+REPO = pathlib.Path(__file__).resolve().parents[1]
 
 
 @pytest.mark.slow
@@ -57,3 +64,49 @@ def test_active_rows_shrinks_zoo_dimension():
     res = train("phi3-mini-3.8b", steps=10, batch=4, seq=32,
                 method="cascaded", active_rows=True, log_every=1000)
     assert np.isfinite(res["loss_last"])
+
+
+def test_serve_full_layers_keeps_published_widths():
+    """``--full --layers 2``: the published widths at a depth of 2."""
+    from repro.configs import get_config
+    published = get_config("phi3-mini-3.8b")
+    res = serve("phi3-mini-3.8b", batch=1, prompt_len=2, gen_len=2,
+                use_reduced=False, n_layers=2, n_clients=1)
+    assert res["model"] == {
+        "n_layers": 2, "d_model": published.d_model,
+        "n_heads": published.n_heads, "n_kv_heads": published.n_kv_heads,
+        "d_ff": published.d_ff, "vocab_size": published.vocab_size}
+    assert (res["model"]["d_model"], res["model"]["vocab_size"]) == (
+        3072, 32064)
+    assert len(res["sample_output"]) == 2
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """The chip check has no CPU fallback: off the TPU it exits non-zero
+    and never prints its ok line."""
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "chip_smoke.py")], cwd=REPO,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), capture_output=True,
+        text=True, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    assert "no TPU" in proc.stderr
+
+
+def test_compile_cache_respects_the_environment(monkeypatch):
+    import jax
+
+    from repro.launch import compile_cache
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.cache_dir_to_set() is None
+    assert compile_cache.enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_dir_is_fixed_inside_the_checkout(monkeypatch):
+    from repro.launch import compile_cache
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    first = compile_cache.cache_dir_to_set()
+    assert first == compile_cache.cache_dir_to_set()
+    assert pathlib.Path(first) == REPO / ".jax_cache"

@@ -1,0 +1,117 @@
+"""Compile the main path's kernels and train step for a TPU v5e, no chip.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+described ``v5e:2x2`` topology. What it refuses (a block that breaks the
+TPU tiling, more scoped VMEM than a program may use, a program larger
+than the chip's 16 GB) fails here at no chip time. Nothing runs, so
+these tests say nothing about results or times.
+
+The topology is described inside a fixture, never while a module is
+imported: only one process at a time may load the TPU library, and
+pytest-xdist workers all import every test file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+V5E_HBM_BYTES = 16 * 10 ** 9
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    old_log_dir = os.environ.get("TPU_LOG_DIR")
+    os.environ["TPU_LOG_DIR"] = "disabled"     # no compiler logs under /tmp
+    # a program compiled for a described chip cannot be read back from
+    # the persistent cache here; keep such compiles out of it
+    old_cache = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            yield topologies.get_topology_desc(platform="tpu",
+                                               topology_name="v5e:2x2")
+        except Exception as e:   # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", old_cache)
+        if old_log_dir is None:
+            os.environ.pop("TPU_LOG_DIR", None)
+        else:
+            os.environ["TPU_LOG_DIR"] = old_log_dir
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _on(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["plain", "bias_relu"])
+@pytest.mark.parametrize("M,K,N,dtype", [
+    (256, 196, 128, "float32"),     # the paper's tabular client, M = 4
+    (256, 3072, 3072, "bfloat16"),  # a client at phi3-mini's hidden width
+    # full-K stripes that need more than the default 16 MB of scoped VMEM
+    (128, 7168, 256, "float32"),
+], ids=["paper_f32", "wide_bf16", "deep_k_f32"])
+def test_stacked_kernels_compile(one_chip, fused, M, K, N, dtype):
+    from repro.kernels.zoo_dual_matmul.kernel import (
+        zoo_dual_matmul_stacked_bias_relu_pallas,
+        zoo_dual_matmul_stacked_pallas)
+    q = 4
+    dtype = jnp.dtype(dtype)
+    args = [_on(one_chip, (M, K), dtype), _on(one_chip, (K, N), dtype),
+            _on(one_chip, (q, K, N), dtype)]
+    if fused:
+        fn = zoo_dual_matmul_stacked_bias_relu_pallas
+        args += [_on(one_chip, (N,), dtype), _on(one_chip, (q, N), dtype)]
+    else:
+        fn = zoo_dual_matmul_stacked_pallas
+    args.append(_on(one_chip, (), jnp.float32))
+    compiled = jax.jit(lambda *a: fn(*a, interpret=False)).lower(
+        *args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_cascaded_train_step_fits_one_chip(one_chip):
+    """The sync cascaded step at phi3-mini's published widths, 2 layers,
+    batch 8 x seq 512, q = 4: the program fits one v5e's memory."""
+    from repro.configs import VFLConfig, driver_config
+    from repro.core.async_engine import EngineConfig
+    from repro.federation import Federation
+    from repro.models import common
+    from repro.optim import make_schedule, sgd
+
+    batch, seq = 8, 512
+    cfg = driver_config("phi3-mini-3.8b", use_reduced=False, n_layers=2)
+    assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab_size) == (
+        3072, 32, 8192, 32064)
+    fed = Federation.build(cfg, VFLConfig(zoo_queries=4),
+                           EngineConfig(method="cascaded", batch_size=batch),
+                           seq_len=seq)
+    opt = sgd(make_schedule("constant", 0.01))
+    step = fed.sync_step(opt)
+
+    def place(x):
+        return _on(one_chip, x.shape, x.dtype)
+
+    params = jax.tree.map(place, common.abstract(fed.model.param_specs))
+    opt_state = jax.tree.map(place, jax.eval_shape(opt.init, params))
+    tokens = _on(one_chip, (batch, seq), jnp.int32)
+    key = place(jax.eval_shape(lambda: jax.random.key(0)))
+    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+        params, opt_state, {"tokens": tokens, "labels": tokens},
+        key).compile()
+    mem = compiled.memory_analysis()
+    peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    assert 0 < peak < V5E_HBM_BYTES, peak
