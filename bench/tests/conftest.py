@@ -1,0 +1,12 @@
+"""CPU tests of the benchmark (``python -m pytest bench/tests``): they run
+the harness at small sizes with JAX on the CPU, and never need a chip."""
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+for p in (os.path.join(ROOT, "src"), ROOT):
+    if p not in sys.path:
+        sys.path.insert(0, p)
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
