@@ -72,6 +72,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
+import time
 from typing import Optional, Tuple
 
 import jax
@@ -86,6 +87,7 @@ from repro.core.adapters import ModelAdapter, tabular_adapter
 from repro.core.methods import SYNC_METHODS
 from repro.core.privacy import Ledger, Message
 from repro.sharding.rules import PARAM_RULES, resolve_spec
+from repro.utils import spans
 
 CLIENT_AXIS = "data"        # mesh axis the client block shards over
 
@@ -188,7 +190,56 @@ def _session_run(adapter: ModelAdapter, transport, vfl: VFLConfig,
 
     ``transport`` (a ``repro.federation.Transport``) supplies the
     canonical method, the wire ledger, and the downlink noise hook; the
-    session supplies the adapter and the (already-built) mesh."""
+    session supplies the adapter and the (already-built) mesh.
+
+    Spans (:mod:`repro.utils.spans`): ``vfl.engine.prepare`` from entry
+    to the scan's dispatch (schedule, sample indices, keys, the eager
+    ``table0``); ``vfl.engine.scan``, the runner call; and
+    ``vfl.engine.collect``, the reads of the losses and delays and the
+    transport's accounting. The device has nothing of the engine queued
+    in ``prepare`` and after ``collect``'s last blocking read: both
+    stretches are recorded as ``vfl.engine.drained``
+    (``after=entry|collect``)."""
+    t_entry = time.time_ns()
+    with spans.span("vfl.engine.prepare"):
+        runner, args, embed, n_active = _prepare(
+            adapter, transport, vfl, cfg_engine, params, x_parts, y,
+            probs, mesh)
+    spans.record("vfl.engine.drained", t_entry, time.time_ns(),
+                 after="entry")
+    with spans.span("vfl.engine.scan"):
+        (params, table, delays), (losses, maxd) = runner(*args)
+    T, bs = cfg_engine.steps, cfg_engine.batch_size
+    with spans.span("vfl.engine.collect"):
+        losses = np.asarray(losses)
+        max_delay_seen = int(jnp.max(maxd))
+        mean_delay = float(jnp.mean(delays))
+        t_read = time.time_ns()
+        # the Transport owns the q-gating (queries only fan out on ZOO
+        # wires)
+        ledger = transport.account(batch=bs, embed=embed,
+                                   zoo_queries=vfl.zoo_queries,
+                                   n_clients=n_active, n_rounds=T)
+        eps, delta = transport.privacy_spent(transport.releases(
+            n_rounds=T, n_clients=n_active, zoo_queries=vfl.zoo_queries))
+        res = EngineResult(params=params, losses=losses,
+                           max_delay_seen=max_delay_seen,
+                           mean_delay=mean_delay,
+                           wire_bytes=ledger.total_bytes,
+                           transmits_gradients=ledger.transmits_gradients,
+                           ledger=ledger, epsilon=eps, delta=delta,
+                           table=table)
+    spans.record("vfl.engine.drained", t_read, time.time_ns(),
+                 after="collect")
+    return res
+
+
+def _prepare(adapter: ModelAdapter, transport, vfl: VFLConfig,
+             cfg_engine: EngineConfig, params, x_parts, y, probs,
+             mesh: Optional[Mesh]):
+    """Everything before the scan: checks, the round schedule, sample
+    indices and keys, the initial table and the compiled runner. Returns
+    ``(runner, its arguments, embedding width, clients a round)``."""
     method = transport.method
     M, n, f = x_parts.shape
     T, bs = cfg_engine.steps, cfg_engine.batch_size
@@ -228,24 +279,9 @@ def _session_run(adapter: ModelAdapter, transport, vfl: VFLConfig,
 
     runner = _make_runner(adapter, transport, vfl, sync, block,
                           cfg_engine.use_lanes, mesh, table_spec)
-    (params, table, delays), (losses, maxd) = runner(
-        params, table0, delays0, schedule, sample_idx, zoo_keys, x_parts, y)
-
-    # the Transport owns the q-gating (queries only fan out on ZOO wires)
-    ledger = transport.account(batch=bs, embed=int(table0.shape[-1]),
-                               zoo_queries=vfl.zoo_queries,
-                               n_clients=M if sync else block, n_rounds=T)
-    eps, delta = transport.privacy_spent(transport.releases(
-        n_rounds=T, n_clients=M if sync else block,
-        zoo_queries=vfl.zoo_queries))
-
-    return EngineResult(params=params, losses=np.asarray(losses),
-                        max_delay_seen=int(jnp.max(maxd)),
-                        mean_delay=float(jnp.mean(delays)),
-                        wire_bytes=ledger.total_bytes,
-                        transmits_gradients=ledger.transmits_gradients,
-                        ledger=ledger, epsilon=eps, delta=delta,
-                        table=table)
+    args = (params, table0, delays0, schedule, sample_idx, zoo_keys,
+            x_parts, y)
+    return runner, args, int(table0.shape[-1]), M if sync else block
 
 
 # ------------------------------------------------------------------------
@@ -441,30 +477,33 @@ def _make_async_step(adapter: ModelAdapter, transport, vfl: VFLConfig,
         c_batch = c_stale.at[m_blk].set(c_fresh)
 
         # ---- server update (sees every activated client fresh) ----------
-        server, h = _server_update(adapter, method, vfl, server, c_batch,
-                                   yb, key)
+        with jax.named_scope("engine.server_step"):
+            server, h = _server_update(adapter, method, vfl, server,
+                                       c_batch, yb, key)
 
         # ---- client updates (concurrent: each sees others STALE) --------
-        keys = _row_keys(key, jnp.arange(m_blk.shape[0]))
-        if method == "vafl":
-            g_blk = jax.vmap(
-                lambda m, cm, xm: client_foo_grad(server, c_stale, m, cm,
-                                                  xm, yb)
-            )(m_blk, client_blk, x_blk)
-        else:
-            g_blk = jax.vmap(
-                lambda m, cm, xm, k: client_zoo_grad(server, c_stale, m, cm,
-                                                     xm, yb, k)
-            )(m_blk, client_blk, x_blk, keys)
-        new_client_blk = jax.tree.map(
-            lambda cm, g: (cm - vfl.lr_client * g).astype(cm.dtype),
-            client_blk, g_blk)
-        clients = jax.tree.map(
-            lambda all_, new: all_.at[m_blk].set(new), clients,
-            new_client_blk)
+        with jax.named_scope("engine.client_zoo"):
+            keys = _row_keys(key, jnp.arange(m_blk.shape[0]))
+            if method == "vafl":
+                g_blk = jax.vmap(
+                    lambda m, cm, xm: client_foo_grad(server, c_stale, m,
+                                                      cm, xm, yb)
+                )(m_blk, client_blk, x_blk)
+            else:
+                g_blk = jax.vmap(
+                    lambda m, cm, xm, k: client_zoo_grad(server, c_stale, m,
+                                                         cm, xm, yb, k)
+                )(m_blk, client_blk, x_blk, keys)
+            new_client_blk = jax.tree.map(
+                lambda cm, g: (cm - vfl.lr_client * g).astype(cm.dtype),
+                client_blk, g_blk)
+            clients = jax.tree.map(
+                lambda all_, new: all_.at[m_blk].set(new), clients,
+                new_client_blk)
 
         # refresh the table with the block's (pre-update) fresh embeddings
-        table = table.at[m_blk[:, None], idx[None, :]].set(c_fresh)
+        with jax.named_scope("engine.table_write"):
+            table = table.at[m_blk[:, None], idx[None, :]].set(c_fresh)
         return {"clients": clients, "server": server}, table, h
 
     return step

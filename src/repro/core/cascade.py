@@ -84,11 +84,12 @@ def make_cascaded_step(loss_fn: Callable, client_keys: Tuple[str, ...],
             # dispatch overhead are constant in q. Gradient flows from the
             # clean lane only (zero cotangent on the perturbed lanes) —
             # numerically identical to the unrolled oracle below.
-            u_stack, d_eff = zoo.sample_directions(
-                key, client, vfl.zoo_queries, vfl.zoo_dist, row_mask)
-            phi = zoo.phi_factor(vfl.zoo_dist, d_eff)
-            lanes = zoo.stack_lanes(jax.lax.stop_gradient(client),
-                                    u_stack, vfl.mu)
+            with jax.named_scope("cascade.client_lanes"):
+                u_stack, d_eff = zoo.sample_directions(
+                    key, client, vfl.zoo_queries, vfl.zoo_dist, row_mask)
+                phi = zoo.phi_factor(vfl.zoo_dist, d_eff)
+                lanes = zoo.stack_lanes(jax.lax.stop_gradient(client),
+                                        u_stack, vfl.mu)
 
             def server_loss(server_p):
                 losses = jax.vmap(
@@ -96,14 +97,16 @@ def make_cascaded_step(loss_fn: Callable, client_keys: Tuple[str, ...],
                 )(lanes)
                 return losses[0], losses
 
-            (loss_clean, losses), g_server = jax.value_and_grad(
-                server_loss, has_aux=True)(server)
+            with jax.named_scope("cascade.server_fwd_bwd"):
+                (loss_clean, losses), g_server = jax.value_and_grad(
+                    server_loss, has_aux=True)(server)
             # the client builds Eq. 3 from the losses it RECEIVES — under
             # a DP transport those are the clipped+noised downlink values
-            recv = (losses if transport is None
-                    else transport.downlink(losses, key))
-            g_client = zoo.grad_from_losses(u_stack, recv[1:], recv[0],
-                                            vfl.mu, phi)
+            with jax.named_scope("cascade.client_update"):
+                recv = (losses if transport is None
+                        else transport.downlink(losses, key))
+                g_client = zoo.grad_from_losses(u_stack, recv[1:], recv[0],
+                                                vfl.mu, phi)
             loss_pert = losses[1]
         else:
             # ---- unrolled oracle (test-only): per-query Python loop,
@@ -136,11 +139,15 @@ def make_cascaded_step(loss_fn: Callable, client_keys: Tuple[str, ...],
             loss_pert = lps[0]
 
         # ---- updates (separate lrs per party, paper §VI-A-d) -------------
-        grads = merge_params(
-            jax.tree.map(lambda g: g * (vfl.lr_client / vfl.lr_server),
-                         g_client),
-            g_server)
-        new_params, new_opt_state = optimizer.update(grads, opt_state, params)
+        # one optimizer update applies the server's gradient and the
+        # client's (Eq. 3's, rescaled to the client lr)
+        with jax.named_scope("cascade.server_update"):
+            grads = merge_params(
+                jax.tree.map(lambda g: g * (vfl.lr_client / vfl.lr_server),
+                             g_client),
+                g_server)
+            new_params, new_opt_state = optimizer.update(grads, opt_state,
+                                                         params)
 
         out = StepOutput(
             loss=loss_clean, loss_perturbed=loss_pert,

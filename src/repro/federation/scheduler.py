@@ -84,6 +84,22 @@ solo path, so a request's tokens do not depend on what shared the batch.
   mid-drain (``run(max_steps=...)`` then kill) continues bitwise — same
   token streams, byte-identical per-request ledgers — mirroring the
   async training plane's ``AsyncPlaneState`` contract.
+
+**Spans** (:mod:`repro.utils.spans`, kept while a profiler session
+records): ``vfl.sched.run`` around :meth:`ServeScheduler.run`; inside it
+``vfl.sched.admit`` per admitted wave (``rids``), holding
+``vfl.sched.prefill_wave`` (``width``, ``prompt_len``) and
+``vfl.sched.install``; ``vfl.sched.block`` per decode block (``k``,
+``occupancy``); ``vfl.sched.retire`` per retirement wave, holding
+``vfl.sched.retire_fetch``, its blocking read. Two kinds of interval are
+recorded after the fact: ``vfl.sched.queued`` (``rid``), a request's wait
+from joining the queue to admission; and ``vfl.sched.drained``
+(``after=entry|retire|evict``), a stretch in which the scheduler's host
+code runs while none of its compiled programs (prefill chunk, replay
+step, install, decode block) is queued on the device — from ``run``'s
+entry or the return of a blocking read to the next such dispatch or
+``run``'s exit. Small eager updates and the block-table upload count as
+host work.
 """
 from __future__ import annotations
 
@@ -101,6 +117,7 @@ from repro.checkpoint.io import load_tree, save_checkpoint
 from repro.core.adapters import ModelAdapter
 from repro.core.privacy import Ledger, Message
 from repro.federation import paging, serving
+from repro.utils import spans
 
 
 class QueueFull(RuntimeError):
@@ -122,6 +139,11 @@ class ServeRequest:
         default_factory=lambda: np.zeros(0, np.int32))
     preemptions: int = 0
     first_admitted: int = -1        # -1 = never admitted
+    # when the request last joined the queue (``time.time_ns``; submit,
+    # preemption or restore): the start of its ``vfl.sched.queued``
+    # interval. Kept out of snapshots and ledgers.
+    queued_ns: int = dataclasses.field(default_factory=time.time_ns,
+                                       compare=False, repr=False)
 
 
 @dataclasses.dataclass
@@ -244,14 +266,15 @@ def make_paged_decode_block(adapter: ModelAdapter, n_clients: int,
         def body(carry, _):
             logits, caches, t, gen_pos, rem, gen_buf = carry
             active = (rem > 0).astype(jnp.int32)
-            nxt = jax.vmap(
-                lambda lg, kd, tt: serving.sample_token(
-                    lg, jax.random.wrap_key_data(kd), tt, temperature,
-                    vocab_size))(logits, keydata_st, t)        # (n, 1)
-            nxt = nxt[:, 0]
-            idx = jnp.clip(gen_pos, 0, gen_buf.shape[1] - 1)
-            gen_buf = gen_buf.at[sl, idx].set(
-                jnp.where(active > 0, nxt, gen_buf[sl, idx]))
+            with jax.named_scope("serve.sample"):
+                nxt = jax.vmap(
+                    lambda lg, kd, tt: serving.sample_token(
+                        lg, jax.random.wrap_key_data(kd), tt, temperature,
+                        vocab_size))(logits, keydata_st, t)    # (n, 1)
+                nxt = nxt[:, 0]
+                idx = jnp.clip(gen_pos, 0, gen_buf.shape[1] - 1)
+                gen_buf = gen_buf.at[sl, idx].set(
+                    jnp.where(active > 0, nxt, gen_buf[sl, idx]))
 
             m = jnp.where(active > 0, t, 0) // span
 
@@ -259,10 +282,13 @@ def make_paged_decode_block(adapter: ModelAdapter, n_clients: int,
                 client_m = jax.tree.map(lambda a: a[mi], params["clients"])
                 return adapter.client_embed(client_m, tok[None, None])
 
-            e = jax.vmap(embed_one)(nxt, m)[:, 0]              # (n, 1, d)
-            e = e * (active > 0).astype(e.dtype)[:, None, None]
-            lg, caches = adapter.server_decode_paged(
-                params["server"], e, caches, tables, t, active, page_size)
+            with jax.named_scope("serve.client_embed"):
+                e = jax.vmap(embed_one)(nxt, m)[:, 0]          # (n, 1, d)
+                e = e * (active > 0).astype(e.dtype)[:, None, None]
+            with jax.named_scope("serve.server_decode"):
+                lg, caches = adapter.server_decode_paged(
+                    params["server"], e, caches, tables, t, active,
+                    page_size)
             return (lg[:, None], caches, t + active, gen_pos + active,
                     rem - active, gen_buf), None
 
@@ -427,6 +453,11 @@ class ServeScheduler:
         self.preemptions = 0
         self.deadline_misses = 0
         self.poisoned = 0
+        self.admitted = 0           # admissions (a resumed victim's too)
+        self.prefill_waves = 0
+        # (since, after) while none of the scheduler's programs is queued
+        # on the device; closed into a vfl.sched.drained span at dispatch
+        self._drained: Optional[tuple] = None
 
     # ------------------------------------------------------- queueing ----
     def submit(self, prompt, gen_len: int, *, seed: Optional[int] = None,
@@ -528,6 +559,7 @@ class ServeScheduler:
                 prog, dt = serving.compiled_with_timing(
                     chunk_fn, self.params, toks[:, t0:t1], caches, t0, m)
                 self.compile_s += dt
+                self._close_drained()
                 logits, caches = prog(self.params, toks[:, t0:t1], caches,
                                       t0, m)
         else:
@@ -536,6 +568,7 @@ class ServeScheduler:
             prog, dt = serving.compiled_with_timing(
                 step, self.params, toks[:, :1], caches, 0)
             self.compile_s += dt
+            self._close_drained()
             for t in range(prompt_len):
                 logits, caches = prog(self.params, toks[:, t:t + 1],
                                       caches, t)
@@ -561,6 +594,7 @@ class ServeScheduler:
         prog, dt = serving.compiled_with_timing(
             step, self.params, tok0, caches, pl)
         self.compile_s += dt
+        self._close_drained()
         for i, tok in enumerate(np.asarray(req.generated, np.int32)):
             logits, caches = prog(self.params,
                                   np.full((rows, 1), tok, np.int32),
@@ -582,7 +616,9 @@ class ServeScheduler:
         pages = [self.allocator.alloc(paging.pages_needed(
             r.prompt.size + r.gen_len, self.page_size)) for r in reqs]
 
-        logits, caches = self._prefill_wave(reqs)
+        with spans.span("vfl.sched.prefill_wave", width=w,
+                        prompt_len=prompt_len):
+            logits, caches = self._prefill_wave(reqs)
         if gens[0]:
             logits, caches = self._replay_generated(reqs[0], logits, caches)
             if w == 1:
@@ -591,27 +627,32 @@ class ServeScheduler:
             self._logits_st = jnp.zeros(
                 (self.n_rows, 1) + logits.shape[1:], logits.dtype)
 
-        rows = jnp.asarray(np.stack([
-            paging.install_rows(p, eff_len, self.page_size)
-            for p in pages]))
-        kd = np.stack([np.asarray(jax.random.key_data(r.key))
-                       for r in reqs])
-        gen_rows = np.zeros((w, self.seq_len), np.int32)
-        for i, r in enumerate(reqs):
-            gen_rows[i, :r.generated.size] = r.generated
-        fn = make_install_prog(self.adapter, self.seq_len)
-        args = (self._caches_st, self._logits_st, self._t_st,
-                self._gen_pos_st, self._rem_st, self._keydata_st,
-                self._gen_buf_st, caches, logits, rows,
-                np.asarray(slots, np.int32),
-                np.full(w, eff_len, np.int32),
-                np.asarray([r.gen_len - g
-                            for r, g in zip(reqs, gens)], np.int32),
-                kd, gen_rows, np.asarray(gens, np.int32))
-        prog, dt = serving.compiled_with_timing(fn, *args)
-        self.compile_s += dt
-        (self._caches_st, self._logits_st, self._t_st, self._gen_pos_st,
-         self._rem_st, self._keydata_st, self._gen_buf_st) = prog(*args)
+        with spans.span("vfl.sched.install", width=w):
+            rows = jnp.asarray(np.stack([
+                paging.install_rows(p, eff_len, self.page_size)
+                for p in pages]))
+            kd = np.stack([np.asarray(jax.random.key_data(r.key))
+                           for r in reqs])
+            gen_rows = np.zeros((w, self.seq_len), np.int32)
+            for i, r in enumerate(reqs):
+                gen_rows[i, :r.generated.size] = r.generated
+            fn = make_install_prog(self.adapter, self.seq_len)
+            args = (self._caches_st, self._logits_st, self._t_st,
+                    self._gen_pos_st, self._rem_st, self._keydata_st,
+                    self._gen_buf_st, caches, logits, rows,
+                    np.asarray(slots, np.int32),
+                    np.full(w, eff_len, np.int32),
+                    np.asarray([r.gen_len - g
+                                for r, g in zip(reqs, gens)], np.int32),
+                    kd, gen_rows, np.asarray(gens, np.int32))
+            prog, dt = serving.compiled_with_timing(fn, *args)
+            self.compile_s += dt
+            self._close_drained()
+            (self._caches_st, self._logits_st, self._t_st,
+             self._gen_pos_st, self._rem_st, self._keydata_st,
+             self._gen_buf_st) = prog(*args)
+        self.admitted += w
+        self.prefill_waves += 1
 
         for slot, req, page_ids in zip(slots, reqs, pages):
             self._tables[slot, :] = paging.ZERO_PAGE
@@ -700,7 +741,13 @@ class ServeScheduler:
                         continue
                 return
             del self._queue[:len(wave)]
-            self._admit_wave(free[:len(wave)], wave)
+            now = time.time_ns()
+            for req in wave:
+                spans.record("vfl.sched.queued", req.queued_ns, now,
+                             rid=req.rid)
+            with spans.span("vfl.sched.admit",
+                            rids=[req.rid for req in wave]):
+                self._admit_wave(free[:len(wave)], wave)
 
     # ----------------------------------------------------- the engine ----
     def _block_len(self, budget: Optional[int] = None) -> int:
@@ -726,21 +773,23 @@ class ServeScheduler:
         if n_occ == 0:
             return
         k = self._block_len(budget)
-        prog = self._block_progs.get(k)
-        tables = self._device_tables()
-        args = (self.params, tables, self._keydata_st, self._logits_st,
-                self._caches_st, self._t_st, self._gen_pos_st,
-                self._rem_st, self._gen_buf_st)
-        if prog is None:
-            block_fn = make_paged_decode_block(
-                self.adapter, self.n_clients, self.seq_len,
-                self.temperature, self.vocab_size, self.page_size,
-                self.n_rows, k)
-            prog, dt = serving.compiled_with_timing(block_fn, *args)
-            self.compile_s += dt
-            self._block_progs[k] = prog
-        (self._logits_st, self._caches_st, self._t_st, self._gen_pos_st,
-         self._rem_st, self._gen_buf_st) = prog(*args)
+        with spans.span("vfl.sched.block", k=k, occupancy=n_occ):
+            prog = self._block_progs.get(k)
+            tables = self._device_tables()
+            args = (self.params, tables, self._keydata_st, self._logits_st,
+                    self._caches_st, self._t_st, self._gen_pos_st,
+                    self._rem_st, self._gen_buf_st)
+            if prog is None:
+                block_fn = make_paged_decode_block(
+                    self.adapter, self.n_clients, self.seq_len,
+                    self.temperature, self.vocab_size, self.page_size,
+                    self.n_rows, k)
+                prog, dt = serving.compiled_with_timing(block_fn, *args)
+                self.compile_s += dt
+                self._block_progs[k] = prog
+            self._close_drained()
+            (self._logits_st, self._caches_st, self._t_st, self._gen_pos_st,
+             self._rem_st, self._gen_buf_st) = prog(*args)
         self.steps += k
         self.generated_tokens += k * n_occ
         for slot, req in enumerate(self._slot_req):
@@ -762,6 +811,7 @@ class ServeScheduler:
         if self._logits_st is not None:
             finite = bool(np.isfinite(np.asarray(
                 self._logits_st[slot], np.float32)).all())
+        self._drained = (time.time_ns(), "evict")
         self.host_transfers += 1
         return toks.astype(np.int32), finite
 
@@ -842,6 +892,7 @@ class ServeScheduler:
         req.generated = toks
         req.preemptions += 1
         self.preemptions += 1
+        req.queued_ns = time.time_ns()
         self._queue.append(req)
 
     @tags.host_boundary("once-per-wave retirement fetch: one batched "
@@ -860,31 +911,43 @@ class ServeScheduler:
                 if r is not None and self._remaining[s] <= 0]
         if not done:
             return
-        done_idx = jnp.asarray(np.array(done, np.int32))
-        toks_all = np.asarray(self._gen_buf_st[done_idx])
-        fin_all = np.isfinite(np.asarray(
-            self._logits_st[done_idx], np.float32)).reshape(
-                len(done), -1).all(axis=1)
-        self.host_transfers += 1
-        for row, slot in enumerate(done):
-            req = self._slot_req[slot]
-            ran = req.gen_len - req.generated.size
-            self.transport.account_serve(batch=1, embed=self.embed_dim,
-                                         n_steps=ran, n_gen=ran,
-                                         ledger=req.ledger)
-            finite = bool(fin_all[row])
-            if not finite:
-                self.poisoned += 1
-            self._results[req.rid] = RequestResult(
-                rid=req.rid, tokens=toks_all[row, :req.gen_len],
-                ledger=req.ledger, prompt_len=req.prompt.size,
-                admitted_at=int(self._admitted_at[slot]),
-                finished_at=self.steps,
-                status="ok" if finite else "poisoned",
-                preemptions=req.preemptions)
-            self._release_slot(slot, scrub=not finite)
+        with spans.span("vfl.sched.retire", n=len(done)):
+            done_idx = jnp.asarray(np.array(done, np.int32))
+            with spans.span("vfl.sched.retire_fetch"):
+                toks_all = np.asarray(self._gen_buf_st[done_idx])
+                fin_all = np.isfinite(np.asarray(
+                    self._logits_st[done_idx], np.float32)).reshape(
+                        len(done), -1).all(axis=1)
+            self._drained = (time.time_ns(), "retire")
+            self.host_transfers += 1
+            for row, slot in enumerate(done):
+                req = self._slot_req[slot]
+                ran = req.gen_len - req.generated.size
+                self.transport.account_serve(batch=1, embed=self.embed_dim,
+                                             n_steps=ran, n_gen=ran,
+                                             ledger=req.ledger)
+                finite = bool(fin_all[row])
+                if not finite:
+                    self.poisoned += 1
+                self._results[req.rid] = RequestResult(
+                    rid=req.rid, tokens=toks_all[row, :req.gen_len],
+                    ledger=req.ledger, prompt_len=req.prompt.size,
+                    admitted_at=int(self._admitted_at[slot]),
+                    finished_at=self.steps,
+                    status="ok" if finite else "poisoned",
+                    preemptions=req.preemptions)
+                self._release_slot(slot, scrub=not finite)
 
     # ----------------------------------------------------------- drive ----
+    def _close_drained(self) -> None:
+        """A compiled program is about to be queued (or ``run`` is
+        returning): close the drained stretch, if one is open."""
+        if self._drained is not None:
+            since, after = self._drained
+            spans.record("vfl.sched.drained", since, time.time_ns(),
+                         after=after)
+            self._drained = None
+
     @property
     def active(self) -> int:
         return sum(r is not None for r in self._slot_req)
@@ -910,15 +973,18 @@ class ServeScheduler:
         tic = time.perf_counter()
         compile0 = self.compile_s
         start = self.steps
-        while self._queue or self.active:
-            budget = (None if max_steps is None
-                      else max_steps - (self.steps - start))
-            if budget is not None and budget <= 0:
-                break
-            self._admit_free_slots()
-            self._block_step(budget)
-            self._retire_wave()
-        jax.block_until_ready(self._gen_buf_st)
+        with spans.span("vfl.sched.run"):
+            self._drained = (time.time_ns(), "entry")
+            while self._queue or self.active:
+                budget = (None if max_steps is None
+                          else max_steps - (self.steps - start))
+                if budget is not None and budget <= 0:
+                    break
+                self._admit_free_slots()
+                self._block_step(budget)
+                self._retire_wave()
+            jax.block_until_ready(self._gen_buf_st)
+            self._close_drained()
         self.last_run_s = (time.perf_counter() - tic
                            - (self.compile_s - compile0))
         return [self._results[rid]
@@ -1018,6 +1084,8 @@ class ServeScheduler:
                 "preemptions": self.preemptions,
                 "deadline_misses": self.deadline_misses,
                 "poisoned": self.poisoned,
+                "admitted": self.admitted,
+                "prefill_waves": self.prefill_waves,
             },
         }
         return SchedulerState(flat=flat, meta=meta)
@@ -1085,3 +1153,5 @@ class ServeScheduler:
         self.preemptions = int(c["preemptions"])
         self.deadline_misses = int(c["deadline_misses"])
         self.poisoned = int(c["poisoned"])
+        self.admitted = int(c.get("admitted", 0))
+        self.prefill_waves = int(c.get("prefill_waves", 0))

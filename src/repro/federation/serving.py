@@ -243,11 +243,12 @@ def make_prefill_chunk(adapter: ModelAdapter, n_clients: int, seq_len: int):
                reason="chunked-prefill uplink: one whole span embedding per "
                       "chunk; prefill carries no downlink")
     def chunk(params, toks, caches, t0, m):
-        client_m = jax.tree.map(lambda a: a[m], params["clients"])
-        e = marks.wire_boundary(adapter.client_embed(client_m, toks),
-                                kind="emb", direction="up")
-        logits, caches = adapter.server_prefill(params["server"], e, caches,
-                                                t0)
+        with jax.named_scope("serve.prefill"):
+            client_m = jax.tree.map(lambda a: a[m], params["clients"])
+            e = marks.wire_boundary(adapter.client_embed(client_m, toks),
+                                    kind="emb", direction="up")
+            logits, caches = adapter.server_prefill(params["server"], e,
+                                                    caches, t0)
         return logits[:, -1:], caches
 
     return jax.jit(chunk, donate_argnums=(2,))

@@ -200,6 +200,8 @@ def _serve_continuous(arch: str, cfg, *, batch: int, prompt_len: int,
         "decode_tok_per_s": round(total_tokens / max(srv.last_run_s, 1e-9),
                                   1),
         "statuses": statuses,
+        "admitted": srv.admitted,
+        "prefill_waves": srv.prefill_waves,
         "preemptions": srv.preemptions,
         "deadline_misses": srv.deadline_misses,
         "queue_retries": queue_retries,

@@ -54,6 +54,7 @@ from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import common
 from repro.optim import make_schedule, sgd
 from repro.sharding.rules import PARAM_RULES
+from repro.utils import spans
 from repro.wire import FaultPlan
 
 
@@ -126,26 +127,33 @@ def train(arch: str = "", *, steps: int = 100, batch: int = 8,
         start, steps)
     jit_step = jax.jit(step_fn, donate_argnums=(0, 1))
 
-    losses, t0 = [], time.time()
+    # the first step (its compilation included) ends at its loss fetch;
+    # steps_per_s times the steps after it
+    losses, t0, t_first = [], time.perf_counter(), None
     with mesh:
         for i, nb in enumerate(data, start=start):
-            b = {k: jnp.asarray(v) for k, v in nb.items()}
-            if cfg.family == "vlm":
-                b["patch_embeds"] = jnp.zeros(
-                    (batch, cfg.n_vision_tokens, cfg.frontend_dim),
-                    jnp.bfloat16)
-            if cfg.is_encoder_decoder:
-                b["frames"] = jnp.zeros(
-                    (batch, cfg.encoder_seq, cfg.frontend_dim), jnp.bfloat16)
-            params, opt_state, out = jit_step(
-                params, opt_state, b, jax.random.fold_in(key, i))
-            losses.append(float(out.loss))
+            with spans.step("train", i):
+                b = {k: jnp.asarray(v) for k, v in nb.items()}
+                if cfg.family == "vlm":
+                    b["patch_embeds"] = jnp.zeros(
+                        (batch, cfg.n_vision_tokens, cfg.frontend_dim),
+                        jnp.bfloat16)
+                if cfg.is_encoder_decoder:
+                    b["frames"] = jnp.zeros(
+                        (batch, cfg.encoder_seq, cfg.frontend_dim),
+                        jnp.bfloat16)
+                params, opt_state, out = jit_step(
+                    params, opt_state, b, jax.random.fold_in(key, i))
+                losses.append(float(out.loss))
+            if t_first is None:
+                t_first = time.perf_counter()
             if i % log_every == 0:
                 print(f"step {i:5d} loss {losses[-1]:.4f} "
                       f"|g_c|={float(out.grad_client_norm):.3e} "
                       f"|g_s|={float(out.grad_server_norm):.3e}", flush=True)
 
-    wall = time.time() - t0
+    t_end = time.perf_counter()
+    wall = t_end - t0
     n_new = steps - start
     # the Transport owns the wire: one ledger call covers this segment
     # (one activated client party — the embedding owner — per sync round),
@@ -161,7 +169,10 @@ def train(arch: str = "", *, steps: int = 100, batch: int = 8,
         "arch": arch, "method": method, "steps": steps,
         "loss_first": losses[0], "loss_last": float(np.mean(losses[-5:])),
         "wall_s": round(wall, 1),
-        "steps_per_s": round(n_new / wall, 2),
+        "compile_s": round(t_first - t0, 2),
+        # None with a single step: no step ran after the first
+        "steps_per_s": (round((n_new - 1) / (t_end - t_first), 2)
+                        if n_new > 1 else None),
         "wire_bytes_per_round": ledger.total_bytes // max(steps, 1),
         "wire_has_gradients": ledger.transmits_gradients,
     }

@@ -211,6 +211,23 @@ def test_train_resume_keeps_schedule_horizon(tmp_path):
     assert meta2["schedule"] == "cosine"
 
 
+def test_train_reports_first_step_apart_from_steps_per_s():
+    """``compile_s`` is the first step up to its loss fetch (its
+    compilation included); ``steps_per_s`` times only the steps after
+    it, so the two add back up to the loop's wall time."""
+    from repro.launch.train import train
+    res = train("phi3-mini-3.8b", steps=4, batch=2, seq=SEQ,
+                log_every=1000)
+    assert res["compile_s"] > 0 and res["steps_per_s"] > 0
+    # the compiled first step is slower than a steady one, and is out
+    assert res["compile_s"] > 1.0 / res["steps_per_s"]
+    assert res["compile_s"] + 3 / res["steps_per_s"] == pytest.approx(
+        res["wall_s"], abs=0.1, rel=0.05)
+    one = train("phi3-mini-3.8b", steps=1, batch=2, seq=SEQ,
+                log_every=1000)
+    assert one["steps_per_s"] is None and one["compile_s"] > 0
+
+
 def test_train_resume_rejects_exhausted_steps(tmp_path):
     from repro.launch.train import train
     p = str(tmp_path / "ck")
