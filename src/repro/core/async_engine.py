@@ -15,6 +15,14 @@ Per global round t (matching Fig. 2):
     (VAFL); concurrent clients see each other's STALE embeddings only
   * table rows (m, i_t) refresh; delay counters update per §III-C
 
+In the compiled round the table write comes before the server's stale
+read: the write touches only the activated clients' rows, and every
+consumer replaces those rows with the fresh embeddings anyway (a block
+of R > 1 reads its own rows first and puts them back, so concurrent
+clients still see each other stale). So the read is exactly the stale
+one, and XLA updates the carried table in place; the scan carries it
+sample-major, ``(n, M, e)``, the layout the round's row gather wants.
+
 The model plane is abstracted behind :class:`repro.core.adapters.ModelAdapter`,
 so the same scan body drives arbitrary ``repro.models`` client/server
 pairs — the paper's tabular MLP, or any LM-scale ``ModelConfig`` via
@@ -296,17 +304,29 @@ def _make_runner(adapter: ModelAdapter, transport, vfl: VFLConfig,
     lru-cached so benchmark sweeps that re-enter ``run`` with the same
     protocol reuse the compiled executable instead of retracing (the
     Transport is a frozen value object, so a noise-channel change is a
-    cache miss and a no-noise Transport hashes like any other key)."""
+    cache miss and a no-noise Transport hashes like any other key).
+
+    The single-device async scan carries the table sample-major,
+    ``(n, M, e)``: a round's row gather and row write then both index
+    the table's major axis, and the layout the gather wants is the
+    carry's own (in ``(M, n, e)`` the TPU copied the whole table into
+    that layout every round). It is transposed once on entry and once
+    on exit, so callers see ``(M, n, e)``."""
+    sample_major = not sync and mesh is None
     if sync:
         step_fn = _make_sync_step(adapter, transport, vfl)
     elif mesh is not None:
         step_fn = _make_sharded_step(adapter, transport, vfl, use_lanes,
                                      mesh, block, table_spec)
     else:
-        step_fn = _make_async_step(adapter, transport, vfl, use_lanes)
+        step_fn = _make_sample_major_step(adapter, transport, vfl,
+                                          use_lanes)
 
     def scan_all(params, table0, delays0, schedule, sample_idx, zoo_keys,
                  x_parts, y):
+        if sample_major:
+            table0 = jnp.swapaxes(table0, 0, 1)            # (n, M, e)
+
         def body(carry, t_in):
             params, table, delays = carry
             m_blk, idx, k = t_in
@@ -320,8 +340,11 @@ def _make_runner(adapter: ModelAdapter, transport, vfl: VFLConfig,
                 delays = delays.at[m_blk[:, None], idx[None, :]].set(0)
             return (params, table, delays), (loss, jnp.max(delays))
 
-        return jax.lax.scan(body, (params, table0, delays0),
-                            (schedule, sample_idx, zoo_keys))
+        (params, table, delays), outs = jax.lax.scan(
+            body, (params, table0, delays0), (schedule, sample_idx, zoo_keys))
+        if sample_major:
+            table = jnp.swapaxes(table, 0, 1)              # (M, n, e)
+        return (params, table, delays), outs
 
     return jax.jit(scan_all)
 
@@ -460,7 +483,40 @@ def _server_update(adapter: ModelAdapter, method: str, vfl: VFLConfig,
 
 def _make_async_step(adapter: ModelAdapter, transport, vfl: VFLConfig,
                      use_lanes: bool):
-    """One asynchronous round for the activated client block {m_t}."""
+    """One asynchronous round for the activated client block {m_t}, over
+    the server's ``(M, n, e)`` embedding table: the round of
+    :func:`_make_sample_major_step`, with the table transposed in and
+    out (the runner carries it sample-major and skips both). Within the
+    round the table write comes before the stale read; the read stays
+    the stale one, as that function's docstring shows."""
+    step_rows = _make_sample_major_step(adapter, transport, vfl, use_lanes)
+
+    def step(params, table, m_blk, idx, key, x_parts, y):
+        params, rows, h = step_rows(params, jnp.swapaxes(table, 0, 1),
+                                    m_blk, idx, key, x_parts, y)
+        return params, jnp.swapaxes(rows, 0, 1), h
+
+    return step
+
+
+def _make_sample_major_step(adapter: ModelAdapter, transport,
+                            vfl: VFLConfig, use_lanes: bool):
+    """One asynchronous round over the sample-major ``(n, M, e)`` table.
+
+    Within the round the table write comes BEFORE the stale read: the
+    block's fresh embeddings go into rows ``(i_t, m)`` first, then the
+    batch's rows of every client are read back. The read is still the
+    stale one the protocol prescribes. The write changes only the
+    activated clients' rows, so every other client's rows read the same
+    before or after it, and every consumer of ``c_stale`` replaces the
+    activated client's rows with its fresh embedding before using them
+    (``c_batch`` below, ``c_stale.at[m].set`` in the client gradients),
+    whatever the write left there, repeated sample indices included. A
+    block of R > 1 clients must see each other's rows stale, so it
+    reads its own R rows before the write and puts them back. Reading
+    after the write lets XLA update the carried table in place instead
+    of copying the whole table every round
+    (``tests/test_async_sharded.py`` checks the compiled loop)."""
     method = transport.method
     client_zoo_grad, client_foo_grad = _make_client_grad_fns(
         adapter, transport, vfl, use_lanes)
@@ -471,9 +527,21 @@ def _make_async_step(adapter: ModelAdapter, transport, vfl: VFLConfig,
         client_blk = jax.tree.map(lambda a: a[m_blk], clients)   # (R, ...)
         x_blk = x_parts[m_blk[:, None], idx[None, :]]            # (R, bs, f)
 
-        # stale embeddings of all clients for this batch; fresh per block
-        c_stale = table[:, idx]                                  # (M, bs, e)
+        # fresh embeddings per block; stale ones of all clients for this
+        # batch, read after the table write (see the docstring)
         c_fresh = jax.vmap(adapter.client_forward)(client_blk, x_blk)
+        block_stale = None
+        if m_blk.shape[0] > 1:
+            # the block's own rows, read first; the barrier keeps XLA
+            # from fusing this read past the write (which would copy)
+            block_stale, table = jax.lax.optimization_barrier(
+                (table[idx[None, :], m_blk[:, None]], table))  # (R, bs, e)
+        # refresh the table with the block's (pre-update) fresh embeddings
+        with jax.named_scope("engine.table_write"):
+            table = table.at[idx[None, :], m_blk[:, None]].set(c_fresh)
+        c_stale = jnp.swapaxes(table[idx], 0, 1)                 # (M, bs, e)
+        if block_stale is not None:
+            c_stale = c_stale.at[m_blk].set(block_stale)
         c_batch = c_stale.at[m_blk].set(c_fresh)
 
         # ---- server update (sees every activated client fresh) ----------
@@ -500,10 +568,6 @@ def _make_async_step(adapter: ModelAdapter, transport, vfl: VFLConfig,
             clients = jax.tree.map(
                 lambda all_, new: all_.at[m_blk].set(new), clients,
                 new_client_blk)
-
-        # refresh the table with the block's (pre-update) fresh embeddings
-        with jax.named_scope("engine.table_write"):
-            table = table.at[m_blk[:, None], idx[None, :]].set(c_fresh)
         return {"clients": clients, "server": server}, table, h
 
     return step

@@ -278,7 +278,9 @@ class Federation:
     def traceable_train_step(self, *, table_shape=None):
         """The EXACT step closure the jitted scan body runs — sync,
         async, or device-sharded per the engine config — returned
-        untraced so ``jax.make_jaxpr`` can walk it. Signature:
+        untraced so ``jax.make_jaxpr`` can walk it (the async round
+        with its table transposed from the scan's sample-major carry
+        to ``(M, n, e)`` and back, nothing else added). Signature:
         ``step(params, table, m_blk, idx, key, x_parts, y) ->
         (params, table, h)``. The sharded variant needs ``table_shape``
         (the (M, n, e) embedding-table shape) to resolve the table's

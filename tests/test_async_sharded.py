@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import _hlo
 from repro.configs import VFLConfig
 from repro.configs.paper_mlp import PaperMLPConfig
 from repro.core import async_engine
@@ -37,10 +38,28 @@ def setup():
 VFL = VFLConfig(mu=1e-3, lr_server=0.05, lr_client=0.05)
 
 
-def test_sharded_mesh1_block1_bitwise(setup):
-    """The shard_map path on a trivial mesh IS the single-device engine."""
+@pytest.mark.parametrize("block,steps,use_lanes,rows", [
+    (1, 25, False, None),
+    (4, 15, False, None),
+    (1, 25, True, None),
+    (4, 15, True, None),
+    # fewer rows than the batch: every batch repeats sample indices
+    (1, 25, False, 6),
+    (4, 15, True, 6),
+], ids=["block1", "block4", "block1-lanes", "block4-lanes",
+        "block1-repeats", "block4-lanes-repeats"])
+def test_sharded_mesh1_bitwise(setup, block, steps, use_lanes, rows):
+    """The shard_map path on a trivial mesh reads the stale table before
+    writing the round's rows, so it is the oracle for the single-device
+    round, which writes first: every concurrent client still sees the
+    others' rows stale, repeated sample indices included. Blocks too:
+    the gather/psum boundaries are float-exact."""
     cfg, Xp, y, params = setup
-    ec = async_engine.EngineConfig(method="cascaded", steps=25, batch_size=8)
+    if rows is not None:
+        Xp, y = Xp[:, :rows], y[:rows]
+    ec = async_engine.EngineConfig(method="cascaded", steps=steps,
+                                   batch_size=8, block_size=block,
+                                   use_lanes=use_lanes)
     single = async_engine.run(ec, VFL, params, Xp, y)
     shard = async_engine.run(ec, VFL, params, Xp, y,
                              mesh=make_client_mesh(1))
@@ -48,17 +67,25 @@ def test_sharded_mesh1_block1_bitwise(setup):
     for a, b in zip(jax.tree.leaves(single.params),
                     jax.tree.leaves(shard.params)):
         assert jnp.array_equal(a, b)
+    assert single.table.shape == shard.table.shape == (
+        cfg.n_clients, Xp.shape[1], cfg.client_embed)
+    assert jnp.array_equal(single.table, shard.table)
+    assert single.mean_delay == shard.mean_delay
+    assert single.max_delay_seen == shard.max_delay_seen
 
 
-def test_sharded_mesh1_block4_bitwise(setup):
-    """Concurrent blocks too: gather/psum boundaries are float-exact."""
-    cfg, Xp, y, params = setup
-    ec = async_engine.EngineConfig(method="cascaded", steps=15, batch_size=8,
-                                   block_size=4)
-    single = async_engine.run(ec, VFL, params, Xp, y)
-    shard = async_engine.run(ec, VFL, params, Xp, y,
-                             mesh=make_client_mesh(1))
-    assert np.array_equal(single.losses, shard.losses)
+@pytest.mark.parametrize("use_lanes", [True, False],
+                         ids=["lanes", "plain"])
+def test_async_round_updates_table_in_place(use_lanes):
+    """The compiled single-device round writes the server's embedding
+    table in place: no copy of the whole table inside the scan's loop
+    (reading the stale rows before the write made XLA copy it twice a
+    round). ``test_tpu_compile.py`` checks the v5e's program too."""
+    n = 1000
+    hlo = _hlo.async_runner_hlo(n=n, block=1, use_lanes=use_lanes)
+    assert " while(" in hlo
+    assert _hlo.loop_copies(hlo, {f"f32[4,{n},128]",
+                                  f"f32[{n},4,128]"}) == []
 
 
 def test_sharded_eight_virtual_devices():

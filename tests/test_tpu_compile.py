@@ -115,3 +115,19 @@ def test_cascaded_train_step_fits_one_chip(one_chip):
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < peak < V5E_HBM_BYTES, peak
+
+
+@pytest.mark.parametrize("block", [1, 4])
+def test_async_round_updates_table_in_place(one_chip, block):
+    """The paper's tabular job at its size (60,000 rows, chunks of 2,000
+    rounds): the v5e's program keeps the server's embedding table in
+    place inside the scan, with no whole-table copy or layout change a
+    round (before, one 123 MB copy into the gather's layout every
+    round)."""
+    import _hlo
+    n = 60000
+    hlo = _hlo.async_runner_hlo(n=n, block=block, steps=2000,
+                                sharding=one_chip)
+    assert " while(" in hlo
+    assert _hlo.loop_copies(hlo, {f"f32[4,{n},128]",
+                                  f"f32[{n},4,128]"}) == []
