@@ -23,10 +23,11 @@ their whole latency; a failed request counts as beyond any limit).
 
 What is compared (after the window, the program's state freed): a
 sample drawn from the seed of the finished requests, the longest among
-them, is run once through the plain reference (``reference/phi3.py``)
-over prompt + served tokens; ``logit_gap`` is the widest gap by which a
-served token's reference logit lies below the reference's best at its
-position (greedy decoding).
+them, is run once through the plain reference (``reference/lm.py`` over
+the configuration's ``reference/<model_type>.py``) over prompt + served
+tokens; ``logit_gap`` is the widest gap by which a served token's
+reference logit lies below the reference's best at its position (greedy
+decoding).
 """
 from __future__ import annotations
 
@@ -82,10 +83,8 @@ def build(ctx: dict):
     from repro.federation import Federation
     from repro.models.common import is_spec
 
-    from bench.drivers.train_sync import model_config
-
     wl, cfg = ctx["workload"], ctx["config"]
-    mcfg = model_config(cfg)
+    mcfg = harness.load_arch(cfg).model_config(cfg)
     seq_len = int(wl["prompt_len"]) + int(wl["gen"]["max"])
     fed = Federation.build(mcfg, VFLConfig(), EngineConfig(),
                            n_clients=int(wl["n_clients"]), seq_len=seq_len)
@@ -230,11 +229,11 @@ def reference_logits(ctx, weights, seqs, seq_len, mode="f32"):
     import jax.numpy as jnp
     import numpy as np
 
-    from bench.drivers.train_sync import ref_items
-    from bench.reference import phi3 as ref
+    from bench.reference import lm
 
+    arch = harness.model_type(ctx["config"])
+    cfg = dict(harness.load_reference(ctx["config"]).items(ctx["config"]))
     params = weights.all(ctx["seed"])
-    cfg = dict(ref_items(ctx["config"]))
     toks = np.zeros((len(seqs), seq_len), np.int32)
     for i, (p, g) in enumerate(seqs):
         s = np.concatenate([p, g])
@@ -242,8 +241,8 @@ def reference_logits(ctx, weights, seqs, seq_len, mode="f32"):
 
     @jax.jit
     def fwd(params, toks):
-        return ref.logits_of(cfg, ref.make_dot(mode), params,
-                             params["embed"]["table"], toks)
+        return lm.logits_of(arch, cfg, lm.make_dot(mode), params,
+                            params["embed"]["table"], toks)
 
     with jax.default_matmul_precision("highest"):
         out = []

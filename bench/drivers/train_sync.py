@@ -28,26 +28,6 @@ from bench.harness import Check, log
 CHECKS = ("loss_gap", "grad_gap", "change_gap")
 
 
-def model_config(cfg: dict):
-    from repro.configs.base import ModelConfig
-    prog = cfg["program"]
-    return ModelConfig(
-        arch_id=cfg["name"], family="dense", source=cfg["source"],
-        n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        act="swiglu", norm="rmsnorm", pos="rope",
-        rope_theta=float(cfg["rope_theta"]),
-        dtype=prog["activation_dtype"], param_dtype=prog["param_dtype"])
-
-
-def ref_items(cfg: dict) -> tuple:
-    keys = ("num_attention_heads", "num_key_value_heads", "rms_norm_eps",
-            "rope_theta")
-    return tuple((k, cfg[k]) for k in keys)
-
-
 @dataclasses.dataclass
 class Plan:
     batch: int
@@ -89,7 +69,7 @@ def build(ctx: dict) -> Setup:
 
     wl, cfg = ctx["workload"], ctx["config"]
     pl = plan_of(wl)
-    mcfg = model_config(cfg)
+    mcfg = harness.load_arch(cfg).model_config(cfg)
     vfl = VFLConfig(mu=pl.mu, lr_server=pl.lr, lr_client=pl.lr,
                     zoo_queries=pl.q, zoo_dist=wl["zoo_dist"])
     fed = Federation.build(mcfg, vfl,
@@ -240,20 +220,22 @@ def reference_run(ctx: dict, su: Setup, token_batches, *, mode="f32",
     import jax
     import jax.numpy as jnp
 
-    from bench.reference import phi3 as ref
+    from bench.reference import lm
 
+    cfg = ctx["config"]
     pl = su.plan
     params = su.weights.all(ctx["seed"])
     names = harness.leaf_names(params)
-    items = ref_items(ctx["config"])
+    items = harness.load_reference(cfg).items(cfg)
     out = {"loss": []}
     with jax.default_matmul_precision("highest"):
         for i, toks in enumerate(token_batches):
             t = jnp.asarray(toks if batch_rows is None
                             else toks[:batch_rows])
-            params, h0, _ = ref.cascaded_step(
+            params, h0, _ = lm.cascaded_step(
                 params, t, jax.random.fold_in(su.key, i), su.hp,
-                cfg_items=items, mode=mode, q=pl.q)
+                arch=harness.model_type(cfg), cfg_items=items, mode=mode,
+                q=pl.q)
             out["loss"].append(float(h0))
             if i == 0:
                 out["grad"] = dict(zip(names, (

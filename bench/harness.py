@@ -5,7 +5,13 @@ that decide ``correct``.
 Everything a cell needs is found by its name: ``workloads/<cell>.json``
 names its configuration (``configs/<config>.json``) and its driver
 (``drivers/<driver>.py``); ``flops/<config>.py`` counts the work the
-algorithm needs; ``metrics/<metric>.py`` reads one per-layer metric.
+algorithm needs; ``metrics/<metric>.py`` reads one per-layer metric. An
+LM configuration's Hugging Face ``model_type`` names its architecture:
+``arch/<model_type>.py`` maps the configuration to the program's model
+(``model_config(cfg)``), and ``reference/<model_type>.py`` is its plain
+reference (``items(cfg)``, ``hidden(cfg, dot, params, x)``), on which
+``reference/lm.py`` builds the logits, the loss and the cascaded step.
+The CPU tests find a cell's small sizes in ``tests/sizes/<cell>.py``.
 """
 from __future__ import annotations
 
@@ -88,18 +94,39 @@ def leaf_names(tree) -> List[str]:
 
 
 def load_module(kind: str, name: str):
-    """``<bench>/<kind>/<name>.py`` as a module (names may hold dots)."""
+    """``<bench>/<kind>/<name>.py`` as a module (names may hold dots),
+    loaded once for each path."""
     path = os.path.join(BENCH, kind, f"{name}.py")
     if not os.path.exists(path):
         raise BenchError(f"no {kind} module {name!r}: no {path}")
     spec = importlib.util.spec_from_file_location(
-        f"bench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
-    if spec.name in sys.modules:
-        return sys.modules[spec.name]
+        f"bench_{kind}_{name}".translate(str.maketrans("./-", "___")), path)
+    known = sys.modules.get(spec.name)
+    if known is not None and known.__file__ == path:
+        return known
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
     spec.loader.exec_module(mod)
     return mod
+
+
+def model_type(cfg: dict) -> str:
+    """An LM configuration's Hugging Face ``model_type``."""
+    if "model_type" not in cfg:
+        raise BenchError(f"config {cfg.get('name')!r} names no model_type")
+    return cfg["model_type"]
+
+
+def load_arch(cfg: dict):
+    """``arch/<model_type>.py``: ``model_config(cfg)``, the program's
+    model for an LM configuration."""
+    return load_module("arch", model_type(cfg))
+
+
+def load_reference(cfg: dict):
+    """``reference/<model_type>.py``: the architecture's plain reference,
+    ``items(cfg)`` and ``hidden(cfg, dot, params, x)``."""
+    return load_module("reference", model_type(cfg))
 
 
 def peaks() -> dict:

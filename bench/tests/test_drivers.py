@@ -10,7 +10,7 @@ import time
 import pytest
 
 from bench import harness, run
-from bench.tests.sizes import TINY
+from bench.tests.sizing import sizes
 
 SPEC = harness.benchmark_spec()
 CPU = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
@@ -20,7 +20,7 @@ CPU = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 @pytest.mark.parametrize("traced", [False, True])
 def test_driver_end_to_end(cell, traced):
     res = run.run_cell(cell, 2 ** 40 + 11, 0.5, traced, device=CPU,
-                       overrides=TINY[cell], t_start=time.perf_counter())
+                       overrides=sizes(cell).TINY, t_start=time.perf_counter())
     assert list(res)[-1] == "checks"
     assert res["attempted"] > 0 and res["failed"] == 0
     for c in res["checks"].values():

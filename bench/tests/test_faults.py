@@ -2,14 +2,15 @@
 once for each fault the cell can have. Training cells can return their
 state unchanged, or leave out half of each batch and take the mean over
 the rest; serving cells can alter a token where it is produced. No cell
-has an exchange between chips (one chip each)."""
+has an exchange between chips (one chip each). The faults are listed by
+driver, so that a new cell on a driver gets its driver's faults."""
 import dataclasses
 import time
 
 import pytest
 
-from bench import run
-from bench.tests.sizes import TINY
+from bench import harness, run
+from bench.tests.sizing import sizes
 
 CPU = {"platform": "cpu", "kind": "TPU v5 lite", "count": 1}
 
@@ -56,17 +57,24 @@ def altered_token(srv):
     return srv
 
 
-FAULTS = [("phi3-serve-decode", altered_token),
-          ("phi3-serve-prefill", altered_token),
-          ("phi3-train-q4", unchanged_step), ("phi3-train-q4",
-                                              half_batch_step),
-          ("mlp-async", unchanged_run), ("mlp-async", half_batch_run)]
+FAULTS = {"serve_closed": [altered_token],
+          "train_sync": [unchanged_step, half_batch_step],
+          "async_engine": [unchanged_run, half_batch_run]}
+CELLS = [(w["name"], harness.load_workload(w["name"])["driver"])
+         for w in harness.benchmark_spec()["workloads"]]
+CASES = [(cell, fault) for cell, driver in CELLS
+         for fault in FAULTS.get(driver, [])]
 
 
-@pytest.mark.parametrize("cell,fault", FAULTS,
-                         ids=[f"{c}-{f.__name__}" for c, f in FAULTS])
+@pytest.mark.parametrize("cell,driver", CELLS)
+def test_every_driver_has_faults(cell, driver):
+    assert FAULTS.get(driver), f"no faults listed for driver {driver!r}"
+
+
+@pytest.mark.parametrize("cell,fault", CASES,
+                         ids=[f"{c}-{f.__name__}" for c, f in CASES])
 def test_broken_timed_path_is_not_correct(cell, fault):
     res = run.run_cell(cell, 31, 0.3, False, device=CPU,
-                       overrides=TINY[cell], wrap_step=fault,
+                       overrides=sizes(cell).TINY, wrap_step=fault,
                        t_start=time.perf_counter())
     assert res["correct"] is False, res["checks"]
