@@ -36,6 +36,13 @@ class ModelConfig:
     norm: str = "rmsnorm"          # rmsnorm | layernorm
     pos: str = "rope"              # rope | learned | none
     rope_theta: float = 10_000.0
+    rope_scaling: str = "none"     # none | yarn (arXiv:2309.00071)
+    rope_factor: float = 1.0       # yarn: context extension factor
+    rope_orig_max_pos: int = 0     # yarn: original_max_position_embeddings
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale_all_dim: float = 0.0  # yarn: softmax scale x mscale^2
+    rope_interleaved: bool = False # rotate pairs (2i, 2i+1), not halves
     tie_embeddings: bool = False
     attn_logit_softcap: float = 0.0
     qk_norm: bool = False
@@ -55,6 +62,18 @@ class ModelConfig:
     load_balance_coef: float = 0.01
     moe_groups: int = 16           # dispatch groups per row (= model-axis
                                    # size: local dispatch + all-to-all EP)
+    router_score: str = "softmax"  # softmax | sigmoid (deepseek-v3
+                                   # "noaux_tc": a correction bias used for
+                                   # choosing only, no aux loss)
+    router_groups: int = 1         # expert groups (n_group)
+    router_groups_kept: int = 1    # groups a token chooses from (topk_group)
+    routed_scale: float = 1.0      # routed_scaling_factor on the sigmoid
+                                   # top-k gates, normalised to sum 1
+    # the share of the routed experts held here (expert parallelism): the
+    # router scores all n_experts, the layer holds [expert_first,
+    # expert_first + n_experts_held) and computes those pairs, dropless
+    n_experts_held: int = 0        # 0 = every expert, capacity dispatch
+    expert_first: int = 0
 
     # MLA (deepseek) ------------------------------------------------------
     use_mla: bool = False
@@ -286,6 +305,8 @@ def reduced(cfg: ModelConfig, **overrides) -> ModelConfig:
                      n_shared_experts=min(cfg.n_shared_experts, 1),
                      first_k_dense=min(cfg.first_k_dense, 1),
                      moe_groups=4)
+        if cfg.router_groups > 1:
+            small.update(router_groups=2, router_groups_kept=1)
     if cfg.use_mla:
         small.update(q_lora_rank=32, kv_lora_rank=32, qk_nope_dim=16,
                      qk_rope_dim=16, v_head_dim=32, n_mtp=min(cfg.n_mtp, 1))

@@ -4,7 +4,12 @@
 61L d_model=7168 128H d_ff(dense)=18432 per-expert d_ff=2048 vocab=129280.
 First 3 layers are dense; the rest are MoE. Attention is Multi-head Latent
 Attention (MLA): the KV cache stores only the compressed latent
-(kv_lora_rank + qk_rope_dim per token).
+(kv_lora_rank + qk_rope_dim per token). The router is sigmoid
+"noaux_tc": a correction bias steers the choice only, a token chooses
+from the 4 best of 8 expert groups, and its 8 gates are normalised and
+scaled by 2.5. RoPE on the 64 rope dims is YaRN (factor 40 over 4,096
+original positions), which also scales the softmax by mscale^2
+(HF ``deepseek-ai/DeepSeek-V3`` config.json).
 """
 from repro.configs.base import ModelConfig
 
@@ -21,11 +26,23 @@ CONFIG = ModelConfig(
     act="swiglu",
     norm="rmsnorm",
     pos="rope",
+    rope_theta=10_000.0,
+    rope_scaling="yarn",
+    rope_factor=40.0,
+    rope_orig_max_pos=4096,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale_all_dim=1.0,
+    rope_interleaved=True,
     n_experts=256,
     top_k=8,
     moe_d_ff=2048,
     n_shared_experts=1,
     first_k_dense=3,
+    router_score="sigmoid",
+    router_groups=8,
+    router_groups_kept=4,
+    routed_scale=2.5,
     use_mla=True,
     q_lora_rank=1536,
     kv_lora_rank=512,

@@ -63,14 +63,16 @@ class ModelAdapter:
     * ``server_decode(server, x, caches, cur_pos)`` -> (logits, caches):
       backbone + head over the uploaded embedding; KV/SSM caches and
       logits never leave the server.
-    * ``server_prefill(server, x, caches, t0)`` -> (logits, caches):
-      consume a whole (bs, chunk, d) span upload in ONE compiled pass
-      (positions t0 .. t0+chunk) — the chunked-prefill hook. Optional:
-      the serve engine falls back to the per-token step loop for
-      adapters that leave it ``None``.
+    * ``server_prefill(server, x, caches, t0)`` -> (logits, caches,
+      stats): consume a whole (bs, chunk, d) span upload in ONE compiled
+      pass (positions t0 .. t0+chunk) — the chunked-prefill hook.
+      ``stats`` is the backbone's (``transformer.backbone_apply``):
+      per-row expert load where the expert layers hold a share, else
+      empty. Optional: the serve engine falls back to the per-token step
+      loop for adapters that leave it ``None``.
     * ``cache_specs(batch, max_seq)``     -> decode-state spec tree.
     * ``server_decode_paged(server, x, caches, tables, cur_pos, active,
-      page_size)`` -> (logits, caches): the continuous scheduler's
+      page_size)`` -> (logits, caches, stats): the continuous scheduler's
       batched paged decode step — x is (n_slots, 1, d) uploads, caches
       carry sequence leaves as shared page pools, ``tables`` (n_slots,
       pages_per_seq) block tables, ``cur_pos``/``active`` per-slot
@@ -336,7 +338,7 @@ def from_model_config(cfg: ModelConfig, *, n_clients: int = 2,
                           axis=0)
             x = x + pe.astype(x.dtype)
         x = shard_constraint(x, ("batch", None, "embed_act"))
-        h, _, aux = transformer.backbone_apply(cfg, server, x,
+        h, _, aux, _ = transformer.backbone_apply(cfg, server, x,
                                                positions=positions)
         h = apply_norm(cfg, server["final_norm"], h)
         logits = unembed(server["lm_head"], h)
@@ -375,18 +377,19 @@ def from_model_config(cfg: ModelConfig, *, n_clients: int = 2,
                           axis=0)
             x = x + pe.astype(x.dtype)
         x = shard_constraint(x, ("batch", None, "embed_act"))
-        h, new_caches, _ = transformer.backbone_apply(
+        h, new_caches, _, stats = transformer.backbone_apply(
             cfg, server, x, positions=positions, caches=caches,
             cur_pos=cur_pos)
         h = apply_norm(cfg, server["final_norm"], h)
         logits = unembed(server["lm_head"], h)
         logits = shard_constraint(logits, ("batch", None, "vocab_act"))
-        return logits, new_caches
+        return logits, new_caches, stats
 
     @tags.party("server")
     def server_decode(server, x, caches, cur_pos):
-        return _decode_tail(server, x, caches, cur_pos,
-                            jnp.asarray(cur_pos)[None])
+        logits, new_caches, _ = _decode_tail(server, x, caches, cur_pos,
+                                             jnp.asarray(cur_pos)[None])
+        return logits, new_caches
 
     @tags.party("server")
     def server_prefill(server, x, caches, t0):
@@ -415,13 +418,13 @@ def from_model_config(cfg: ModelConfig, *, n_clients: int = 2,
                           axis=0)
             x = x + pe.astype(x.dtype)
         x = shard_constraint(x, ("batch", None, "embed_act"))
-        h, new_caches, _ = transformer.backbone_apply(
+        h, new_caches, _, stats = transformer.backbone_apply(
             cfg, server, x, positions=positions, caches=caches,
             cur_pos=cur_pos, paging=paging_ctx)
         h = apply_norm(cfg, server["final_norm"], h)
         logits = unembed(server["lm_head"], h)
         logits = shard_constraint(logits, ("batch", None, "vocab_act"))
-        return logits, new_caches
+        return logits, new_caches, stats
 
     def cache_specs(batch, max_seq):
         return model_api.build_cache_specs(cfg, batch, max_seq)

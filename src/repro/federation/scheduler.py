@@ -99,7 +99,9 @@ code runs while none of its compiled programs (prefill chunk, replay
 step, install, decode block) is queued on the device — from ``run``'s
 entry or the return of a blocking read to the next such dispatch or
 ``run``'s exit. Small eager updates and the block-table upload count as
-host work.
+host work. Where the expert layers hold a share of the experts, a
+zero-length ``vfl.sched.moe`` record at ``run``'s entry and exit holds
+:meth:`ServeScheduler.moe_counters` (device scalars, unread).
 """
 from __future__ import annotations
 
@@ -286,18 +288,38 @@ def make_paged_decode_block(adapter: ModelAdapter, n_clients: int,
                 e = jax.vmap(embed_one)(nxt, m)[:, 0]          # (n, 1, d)
                 e = e * (active > 0).astype(e.dtype)[:, None, None]
             with jax.named_scope("serve.server_decode"):
-                lg, caches = adapter.server_decode_paged(
+                lg, caches, stats = adapter.server_decode_paged(
                     params["server"], e, caches, tables, t, active,
                     page_size)
+            # the active rows' pairs routed to held experts (none where
+            # the expert layers hold every expert: stats is empty)
+            pairs = {k: jnp.sum(v * active[None, :, None])
+                     for k, v in stats.items()}
             return (lg[:, None], caches, t + active, gen_pos + active,
-                    rem - active, gen_buf), None
+                    rem - active, gen_buf), pairs
 
-        carry, _ = jax.lax.scan(
+        carry, pairs = jax.lax.scan(
             body, (logits_st, caches_st, t_st, gen_pos_st, rem_st,
                    gen_buf_st), None, length=n_steps)
-        return carry
+        return carry + (jax.tree.map(jnp.sum, pairs),)
 
     return jax.jit(block, donate_argnums=(3, 4, 5, 6, 7, 8))
+
+
+@jax.jit
+def _add_wave_load(acc, load, real):
+    """``acc`` plus one prefill chunk's held-expert load (``moe_load`` of
+    the backbone's stats, (layers, rows, held)) over its real rows."""
+    per = jnp.sum(load * real[None, :, None], axis=1)     # (layers, held)
+    return {"pairs": acc["pairs"] + jnp.sum(per),
+            "wave_load_max": acc["wave_load_max"]
+            + jnp.sum(jnp.max(per, axis=-1)),
+            "wave_load_sum": acc["wave_load_sum"] + jnp.sum(per)}
+
+
+@jax.jit
+def _add_decode_pairs(acc, pairs):
+    return dict(acc, pairs=acc["pairs"] + pairs)
 
 
 @functools.lru_cache(maxsize=32)
@@ -455,6 +477,11 @@ class ServeScheduler:
         self.poisoned = 0
         self.admitted = 0           # admissions (a resumed victim's too)
         self.prefill_waves = 0
+        # expert layers that hold a share of the experts (see
+        # moe_counters); None until a prefill shows such layers
+        self.expert_tokens = 0
+        self._moe_acc: Optional[Dict[str, jax.Array]] = None
+        self._moe_shape = (0, 0)    # (expert layers, held experts)
         # (since, after) while none of the scheduler's programs is queued
         # on the device; closed into a vfl.sched.drained span at dispatch
         self._drained: Optional[tuple] = None
@@ -560,8 +587,10 @@ class ServeScheduler:
                     chunk_fn, self.params, toks[:, t0:t1], caches, t0, m)
                 self.compile_s += dt
                 self._close_drained()
-                logits, caches = prog(self.params, toks[:, t0:t1], caches,
-                                      t0, m)
+                logits, caches, stats = prog(self.params, toks[:, t0:t1],
+                                             caches, t0, m)
+                if stats:
+                    self._count_wave_load(stats["moe_load"], w, t1 - t0)
         else:
             step = serving.make_serve_step(self.adapter, self.n_clients,
                                            self.seq_len)
@@ -789,7 +818,11 @@ class ServeScheduler:
                 self._block_progs[k] = prog
             self._close_drained()
             (self._logits_st, self._caches_st, self._t_st, self._gen_pos_st,
-             self._rem_st, self._gen_buf_st) = prog(*args)
+             self._rem_st, self._gen_buf_st, pairs) = prog(*args)
+            if pairs:
+                self._moe_acc = _add_decode_pairs(self._moe_acc,
+                                                  pairs["moe_load"])
+                self.expert_tokens += k * n_occ * self._moe_shape[0]
         self.steps += k
         self.generated_tokens += k * n_occ
         for slot, req in enumerate(self._slot_req):
@@ -938,6 +971,41 @@ class ServeScheduler:
                     preemptions=req.preemptions)
                 self._release_slot(slot, scrub=not finite)
 
+    # --------------------------------------------------- expert layers ----
+    def _count_wave_load(self, load, w: int, chunk: int) -> None:
+        """Add one prefill chunk's held-expert load to the device-side
+        counters (an async dispatch; nothing is read back)."""
+        self._moe_shape = (load.shape[0], load.shape[2])
+        if self._moe_acc is None:
+            self._moe_acc = {k: jnp.zeros((), jnp.int32) for k in
+                             ("pairs", "wave_load_max", "wave_load_sum")}
+        real = (np.arange(load.shape[1]) < w).astype(np.int32)
+        self._moe_acc = _add_wave_load(self._moe_acc, load, real)
+        self.expert_tokens += w * chunk * load.shape[0]
+
+    def moe_counters(self) -> Optional[Dict[str, Any]]:
+        """Counters of the expert layers that hold a share, None where
+        they hold every expert: ``tokens`` through the expert layers
+        (each layer counts a token), ``pairs`` of them routed to held
+        experts, and over the prefill wave steps (a chunk through one
+        expert layer) the sums of the busiest held expert's pairs
+        (``wave_load_max``) and of all held pairs (``wave_load_sum``);
+        ``held`` experts. Only the admitted rows of a wave and the
+        active slots of a decode step count. The device counters come
+        back as device scalars, unread: reading them syncs."""
+        if self._moe_acc is None:
+            return None
+        return {"tokens": self.expert_tokens, "held": self._moe_shape[1],
+                **self._moe_acc}
+
+    def _record_moe(self) -> None:
+        """Keep the expert counters as a ``vfl.sched.moe`` record while a
+        profiler session records (a reader takes the difference of two)."""
+        c = self.moe_counters()
+        if c is not None:
+            now = time.time_ns()
+            spans.record("vfl.sched.moe", now, now, **c)
+
     # ----------------------------------------------------------- drive ----
     def _close_drained(self) -> None:
         """A compiled program is about to be queued (or ``run`` is
@@ -974,6 +1042,7 @@ class ServeScheduler:
         compile0 = self.compile_s
         start = self.steps
         with spans.span("vfl.sched.run"):
+            self._record_moe()
             self._drained = (time.time_ns(), "entry")
             while self._queue or self.active:
                 budget = (None if max_steps is None
@@ -985,6 +1054,7 @@ class ServeScheduler:
                 self._retire_wave()
             jax.block_until_ready(self._gen_buf_st)
             self._close_drained()
+            self._record_moe()
         self.last_run_s = (time.perf_counter() - tic
                            - (self.compile_s - compile0))
         return [self._results[rid]
@@ -1088,6 +1158,10 @@ class ServeScheduler:
                 "prefill_waves": self.prefill_waves,
             },
         }
+        moe = self.moe_counters()
+        if moe is not None:
+            meta["counters"]["moe"] = {k: int(v) for k, v in moe.items()}
+            meta["counters"]["moe_layers"] = self._moe_shape[0]
         return SchedulerState(flat=flat, meta=meta)
 
     @tags.host_boundary("checkpoint restore: rehydrates host-side queue/"
@@ -1155,3 +1229,9 @@ class ServeScheduler:
         self.poisoned = int(c["poisoned"])
         self.admitted = int(c.get("admitted", 0))
         self.prefill_waves = int(c.get("prefill_waves", 0))
+        if "moe" in c:
+            moe = c["moe"]
+            self.expert_tokens = int(moe["tokens"])
+            self._moe_shape = (int(c["moe_layers"]), int(moe["held"]))
+            self._moe_acc = {k: jnp.asarray(int(moe[k]), jnp.int32) for k
+                             in ("pairs", "wave_load_max", "wave_load_sum")}

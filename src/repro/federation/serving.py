@@ -231,8 +231,9 @@ def make_prefill_chunk(adapter: ModelAdapter, n_clients: int, seq_len: int):
     """Jitted chunked-prefill step: client ``m`` embeds its whole
     ``(B, chunk)`` span slice in ONE call and the server consumes the
     ``(B, chunk, d_model)`` upload through ``server_prefill``. Returns
-    only the last position's logits (the decode seed); one compile per
-    distinct chunk length."""
+    only the last position's logits (the decode seed), the caches and the
+    backbone's stats (``server_prefill``'s); one compile per distinct
+    chunk length."""
     _require_serve_plane(adapter)
     if adapter.server_prefill is None:
         raise ValueError(
@@ -247,9 +248,9 @@ def make_prefill_chunk(adapter: ModelAdapter, n_clients: int, seq_len: int):
             client_m = jax.tree.map(lambda a: a[m], params["clients"])
             e = marks.wire_boundary(adapter.client_embed(client_m, toks),
                                     kind="emb", direction="up")
-            logits, caches = adapter.server_prefill(params["server"], e,
-                                                    caches, t0)
-        return logits[:, -1:], caches
+            logits, caches, stats = adapter.server_prefill(
+                params["server"], e, caches, t0)
+        return logits[:, -1:], caches, stats
 
     return jax.jit(chunk, donate_argnums=(2,))
 
@@ -392,7 +393,8 @@ def run_decode(adapter: ModelAdapter, transport, *, n_clients: int,
         tic = time.perf_counter()
         logits = None
         for (t0, t1, m), prog in zip(plan, progs):
-            logits, caches = prog(params, prompts[:, t0:t1], caches, t0, m)
+            logits, caches, _ = prog(params, prompts[:, t0:t1], caches, t0,
+                                     m)
         jax.block_until_ready(logits)
         prefill_s = time.perf_counter() - tic
     else:

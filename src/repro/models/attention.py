@@ -15,7 +15,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.common import ParamSpec
-from repro.models.layers import apply_rope, rms_norm_simple
+from repro.models.layers import (apply_rope, rms_norm_simple, rope_args,
+                                 softmax_scale_factor)
 from repro.sharding.rules import shard_constraint
 
 NEG_INF = -1e30
@@ -56,8 +57,10 @@ def attention_specs(cfg, d: Optional[int] = None):
     return sp
 
 
-def cache_specs(cfg, batch: int, seq: int, dtype="bfloat16"):
-    """Abstract KV-cache layout for decode shapes."""
+def cache_specs(cfg, batch: int, seq: int):
+    """Abstract KV-cache layout for decode shapes, in the activations'
+    dtype."""
+    dtype = cfg.dtype
     hd = cfg.resolved_head_dim
     if cfg.use_mla:
         # MLA caches the compressed latent + shared rope key only.
@@ -220,8 +223,9 @@ def attention_apply(cfg, p, x, *, positions, cache=None, cur_pos=None,
         k = rms_norm_simple(k, p["k_norm"])
 
     if cfg.pos == "rope" and kv_override is None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        rk = rope_args(cfg, hd)
+        q = apply_rope(q, positions, cfg.rope_theta, **rk)
+        k = apply_rope(k, positions, cfg.rope_theta, **rk)
 
     q = shard_constraint(q, ("batch", None, "heads_act", None))
     new_cache = None
@@ -326,28 +330,39 @@ def mla_apply(cfg, p, x, *, positions, cache=None, cur_pos=None,
     """DeepSeek-V3 Multi-head Latent Attention.
 
     Cache stores only (kv_lora_rank + qk_rope_dim) per token; k/v are
-    re-expanded from the latent on use (baseline; the weight-absorbed
-    variant that scores directly in latent space is a §Perf candidate).
-    Query/key are concatenated [nope|rope] so the chunked GQA path is reused
-    (scale = (nd+rd)^-1/2 matches DeepSeek's).
+    re-expanded from the latent for a prefill chunk, and a one-token
+    decode either expands them too or (``mla_absorb``) scores in latent
+    space. Query/key are concatenated [nope|rope] so the chunked GQA path
+    is reused (scale = (nd+rd)^-1/2, times YaRN's mscale^2 where the
+    config has YaRN, as DeepSeek's). Named scopes: ``mla.prefill`` over
+    a multi-token chunk, ``mla.decode`` over a one-token step.
     """
+    with jax.named_scope("mla.decode" if x.shape[1] == 1
+                         else "mla.prefill"):
+        return _mla(cfg, p, x, positions=positions, cache=cache,
+                    cur_pos=cur_pos, window=window, paging=paging)
+
+
+def _mla(cfg, p, x, *, positions, cache, cur_pos, window, paging):
     B, S, d = x.shape
     H = cfg.n_heads
     nd, rd, vd = cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     dt = x.dtype
-    scale = (nd + rd) ** -0.5
+    scale = (nd + rd) ** -0.5 * softmax_scale_factor(cfg)
+    rk = rope_args(cfg, rd)
 
     qa = rms_norm_simple(jnp.einsum("bsd,dr->bsr", x, p["wq_a"]), p["q_norm"])
     q = jnp.einsum("bsr,rh->bsh", qa, p["wq_b"]).reshape(B, S, H, nd + rd)
     q_nope, q_rope = q[..., :nd], q[..., nd:]
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_rope = apply_rope(q_rope, positions, cfg.rope_theta, **rk)
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     q = shard_constraint(q, ("batch", None, "heads_act", None))
 
     kv_a = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"])        # (B,S,lora+rd)
     latent = rms_norm_simple(kv_a[..., :cfg.kv_lora_rank], p["kv_norm"])
     k_rope = apply_rope(kv_a[..., cfg.kv_lora_rank:], positions,
-                        cfg.rope_theta, has_heads=False)   # (B,S,rd) shared
+                        cfg.rope_theta, has_heads=False,
+                        **rk)                              # (B,S,rd) shared
 
     new_cache = None
     if paging is not None:

@@ -117,15 +117,15 @@ def _attn_block_apply(cfg, p, x, *, positions, cache=None, cur_pos=None,
         a = shard_constraint(a, ("batch", "seq_act", "embed_act"))
     x = x + a
     h = apply_norm(cfg, p["ln2"], x)
-    aux = jnp.float32(0.0)
+    aux, load = jnp.float32(0.0), None
     if "moe" in p:
-        m, aux = moe_mod.moe_apply(cfg, p["moe"], h, decode=decode,
-                                   gather_experts=gather_experts)
+        m, aux, load = moe_mod.moe_apply(cfg, p["moe"], h, decode=decode,
+                                         gather_experts=gather_experts)
     else:
         m = mlp_apply(cfg, p["mlp"], h)
     if cfg.rs_outputs:
         m = shard_constraint(m, ("batch", "seq_act", "embed_act"))
-    return x + m, new_cache, aux
+    return x + m, new_cache, aux, load
 
 
 def _rwkv_block_apply(cfg, p, x, *, state=None, active=None):
@@ -186,10 +186,8 @@ def scan_apply(cfg, body, carry, xs, n: int):
         xs_i = jax.tree.map(lambda a: a[i], xs)
         carry, y = body(carry, xs_i)
         ys.append(y)
-    if ys and any(leaf is not None for leaf in jax.tree.leaves(ys[0])):
-        ys_stacked = jax.tree.map(lambda *zs: jnp.stack(zs), *ys)
-    else:
-        ys_stacked = None
+    # None subtrees (no cache, no expert load) stay None
+    ys_stacked = jax.tree.map(lambda *zs: jnp.stack(zs), *ys) if ys else None
     return carry, ys_stacked
 
 
@@ -203,7 +201,10 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
     sequence-indexed cache leaves to the paged-pool layout with per-row
     positions (the continuous scheduler's batched decode step); recurrent
     state leaves are then slot-batched and frozen on inactive rows.
-    Returns (hidden (B,S,d), new_caches, aux_losses).
+    Returns (hidden (B,S,d), new_caches, aux_losses, stats): ``stats``
+    is ``{"moe_load": (moe layers, B, n_experts_held) int32}`` where the
+    expert layers hold a share (pairs per held expert, per row), else
+    empty.
     """
     decode = caches is not None
     active = None if paging is None else paging.active
@@ -217,7 +218,7 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
         body = _maybe_remat(cfg, body)
         x, new_caches = scan_apply(cfg, body, x, (params["blocks"], caches),
                                    cfg.n_layers)
-        return x, new_caches, jnp.float32(0.0)
+        return x, new_caches, jnp.float32(0.0), {}
 
     if cfg.family == "hybrid":
         n_super = cfg.n_layers // cfg.attn_every
@@ -236,7 +237,7 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
             h, new_sts = scan_apply(cfg, inner, h, (p_sup, st_sup),
                                     cfg.attn_every)
             # shared attention block (weights reused across sites)
-            h, new_attn_cache, _ = _attn_block_apply(
+            h, new_attn_cache, _, _ = _attn_block_apply(
                 cfg, shared_p, h, positions=positions, cache=attn_cache,
                 cur_pos=cur_pos, window=window, decode=decode,
                 window_gather=window_gather, paging=paging)
@@ -249,7 +250,7 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
             ssm_states, attn_caches = None, None
         xs = (params["blocks"], ssm_states, attn_caches)
         x, new_caches = scan_apply(cfg, super_body, x, xs, n_super)
-        return x, new_caches, jnp.float32(0.0)
+        return x, new_caches, jnp.float32(0.0), {}
 
     # attention families (dense / moe / vlm backbone)
     aux_total = jnp.float32(0.0)
@@ -257,17 +258,17 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
     def body(carry, xs):
         h, aux = carry
         p_l, c_l = xs
-        h2, new_c, a = _attn_block_apply(
+        h2, new_c, a, load = _attn_block_apply(
             cfg, p_l, _boundary(cfg, h), positions=positions, cache=c_l,
             cur_pos=cur_pos, window=window, decode=decode,
             window_gather=window_gather, gather_experts=gather_experts,
             paging=paging)
-        return (h2, aux + a), new_c
+        return (h2, aux + a), (new_c, load)
     body = _maybe_remat(cfg, body)
 
     if cfg.first_k_dense and cfg.n_experts:
         dense_caches = None if caches is None else caches["dense"]
-        (x, aux_total), new_dense = scan_apply(
+        (x, aux_total), (new_dense, _) = scan_apply(
             cfg, body, (x, aux_total), (params["dense_blocks"], dense_caches),
             cfg.first_k_dense)
     else:
@@ -278,14 +279,15 @@ def backbone_apply(cfg, params, x, *, positions, caches=None, cur_pos=None,
     main_caches = None
     if caches is not None:
         main_caches = caches["main"] if isinstance(caches, dict) and "main" in caches else caches
-    (x, aux_total), new_main = scan_apply(
+    (x, aux_total), (new_main, loads) = scan_apply(
         cfg, body, (x, aux_total), (params["blocks"], main_caches), n_main)
 
     if new_dense is not None:
         new_caches = {"dense": new_dense, "main": new_main}
     else:
         new_caches = new_main
-    return x, (new_caches if decode else None), aux_total
+    stats = {} if loads is None else {"moe_load": loads}
+    return x, (new_caches if decode else None), aux_total, stats
 
 
 # ============================================================== forward ====
@@ -331,7 +333,7 @@ def forward(cfg, params, inputs, *, caches=None, cur_pos=None, window=0,
         positions = jnp.asarray(cur_pos) + jnp.arange(
             inputs["tokens"].shape[1])                  # (S,)
     x = embed_inputs(cfg, params, inputs, positions=positions)
-    h, new_caches, aux = backbone_apply(
+    h, new_caches, aux, _ = backbone_apply(
         cfg, params, x, positions=positions, caches=caches, cur_pos=cur_pos,
         window=window, window_gather=window_gather,
         gather_experts=gather_experts)
@@ -371,7 +373,7 @@ def _mtp_loss(cfg, params, inputs, *, window=0):
     e_next = jnp.concatenate([x[:, 1:], x[:, -1:]], axis=1)
     comb = jnp.concatenate([x, e_next], axis=-1)
     h = jnp.einsum("bsd,de->bse", comb, params["mtp"]["proj"])
-    h, _, _ = _attn_block_apply(cfg, params["mtp"]["block"], h,
+    h, _, _, _ = _attn_block_apply(cfg, params["mtp"]["block"], h,
                                 positions=jnp.arange(h.shape[1]),
                                 window=window)
     h = apply_norm(cfg, params["mtp"]["norm"], h)

@@ -131,3 +131,31 @@ def test_async_round_updates_table_in_place(one_chip, block):
     assert " while(" in hlo
     assert _hlo.loop_copies(hlo, {f"f32[4,{n},128]",
                                   f"f32[{n},4,128]"}) == []
+
+
+def test_held_expert_layer_at_deepseek_widths(one_chip):
+    """One MoE layer of DeepSeek-V3 at its widths holding 8 of the 256
+    experts (one chip of EP32), over a prefill chunk of 4 x 1,024
+    tokens: the v5e's program runs the held pairs in a loop of 128-row
+    tiles through one expert's weights at a time, and never gathers all
+    4,096 x 8 pairs' rows or runs an expert on every token."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import common, moe
+
+    cfg = dataclasses.replace(get_config("deepseek-v3-671b"),
+                              n_experts_held=8)
+    p = jax.tree.map(lambda s: _on(one_chip, s.shape, jnp.dtype(s.dtype)),
+                     moe.moe_specs(cfg, cfg.d_model),
+                     is_leaf=common.is_spec)
+    x = _on(one_chip, (4, 1024, cfg.d_model), jnp.bfloat16)
+    compiled = jax.jit(lambda p, x: moe.moe_apply_held(cfg, p, x)).lower(
+        p, x).compile()
+    hlo = compiled.as_text()
+    assert " while(" in hlo
+    assert "bf16[128,7168]" in hlo and "bf16[128,2048]" in hlo
+    for every in ("[32768,7168]", "[4,1024,8,2048]", "[4096,8,2048]"):
+        assert every not in hlo, every
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 10 ** 9, mem.temp_size_in_bytes
