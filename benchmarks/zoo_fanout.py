@@ -4,7 +4,8 @@ For q ∈ {1, 4, 16} and both cascade code paths —
   * ``unrolled`` — the per-query Python-loop oracle (fused_dual=False):
     q separate server passes, trace size and dispatch linear in q
   * ``stacked``  — the vectorized lane path (fused_dual=True): ALL q
-    directions drawn as stacked leaves, ONE vmapped server pass
+    directions drawn as stacked leaves, ONE vmapped server pass over
+    the perturbed lanes beside the clean lane's forward/backward
 — this records the one-time compile wall clock and the steady-state
 per-round wall clock of the cascaded step. The acceptance claim is that
 the stacked path's per-round time grows SUBLINEARLY in q (the unrolled

@@ -260,10 +260,11 @@ class VFLConfig:
     # learning rates (paper tunes server/client separately)
     lr_server: float = 0.01
     lr_client: float = 0.01
-    # §Perf: the clean + q perturbed forwards run as ONE vmapped server
-    # pass over stacked lanes (FSDP weight all-gathers happen once instead
-    # of 1+q times; compile time constant in q). False selects the unrolled
-    # per-query oracle — test-only numerical reference, never production.
+    # §Perf: the q perturbed forwards run as ONE vmapped server pass over
+    # stacked lanes, beside the clean lane's forward/backward (FSDP
+    # all-gathers the weights for two passes, not 1+q; compile time
+    # constant in q). False selects the unrolled per-query oracle —
+    # test-only numerical reference, never production.
     fused_dual: bool = True
     # test-only: route zoo_gradient through the original per-query Python
     # loop instead of the vectorized lane stack (oracle for equality tests)

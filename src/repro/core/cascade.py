@@ -77,29 +77,36 @@ def make_cascaded_step(loss_fn: Callable, client_keys: Tuple[str, ...],
 
         if vfl.fused_dual:
             # ---- default path: vectorized fan-out. ALL q directions are
-            # drawn as stacked leaves and the server runs ONE vmapped pass
-            # over the (1 + q) lanes {clean, perturbed…}. The server
-            # weights are unbatched inside the vmap, so FSDP all-gathers
-            # them once instead of (1 + q) times, and compile time /
-            # dispatch overhead are constant in q. Gradient flows from the
-            # clean lane only (zero cotangent on the perturbed lanes) —
-            # numerically identical to the unrolled oracle below.
+            # drawn as stacked leaves, so compile time / dispatch overhead
+            # are constant in q. The server backpropagates (Eq. 4) through
+            # the clean lane alone; the q perturbed lanes run ONE vmapped
+            # forward pass, with no residuals kept and no transpose, since
+            # they only give back loss scalars for Eq. 3. The server
+            # weights are unbatched inside the vmap; under FSDP they are
+            # all-gathered for two passes a step (the clean lane's
+            # forward/backward and the lanes' forward), not one per lane.
+            # It computes what the unrolled oracle below computes.
             with jax.named_scope("cascade.client_lanes"):
                 u_stack, d_eff = zoo.sample_directions(
                     key, client, vfl.zoo_queries, vfl.zoo_dist, row_mask)
                 phi = zoo.phi_factor(vfl.zoo_dist, d_eff)
                 lanes = zoo.stack_lanes(jax.lax.stop_gradient(client),
                                         u_stack, vfl.mu)
+                clean = jax.tree.map(lambda a: a[0], lanes)
+                perturbed = jax.tree.map(lambda a: a[1:], lanes)
 
             def server_loss(server_p):
-                losses = jax.vmap(
-                    lambda c: loss_fn(merge_params(c, server_p), batch)[0]
-                )(lanes)
-                return losses[0], losses
+                return loss_fn(merge_params(clean, server_p), batch)[0]
 
             with jax.named_scope("cascade.server_fwd_bwd"):
-                (loss_clean, losses), g_server = jax.value_and_grad(
-                    server_loss, has_aux=True)(server)
+                loss_clean, g_server = jax.value_and_grad(server_loss)(
+                    server)
+            with jax.named_scope("cascade.server_lanes"):
+                frozen = jax.lax.stop_gradient(server)
+                losses_pert = jax.vmap(
+                    lambda c: loss_fn(merge_params(c, frozen), batch)[0]
+                )(perturbed)
+            losses = jnp.concatenate([loss_clean[None], losses_pert])
             # the client builds Eq. 3 from the losses it RECEIVES — under
             # a DP transport those are the clipped+noised downlink values
             with jax.named_scope("cascade.client_update"):

@@ -1,9 +1,12 @@
-"""Compile the single-device async runner and read its optimized HLO.
+"""Compile the single-device async runner and the sync cascaded step,
+and read their optimized HLO.
 
-Shared by the CPU guard in ``test_async_sharded.py`` and the described-v5e
-guard in ``test_tpu_compile.py``: both assert that the scanned round
-updates the server's embedding table in place, with no copy of the
-whole table inside the loop."""
+The async runner is shared by the CPU guard in ``test_async_sharded.py``
+and the described-v5e guard in ``test_tpu_compile.py``: both assert that
+the scanned round updates the server's embedding table in place, with no
+copy of the whole table inside the loop. The cascaded step is compiled
+for a described v5e in ``test_tpu_compile.py``: it must fit the chip and
+backpropagate the server through the clean lane alone."""
 from __future__ import annotations
 
 import re
@@ -79,3 +82,36 @@ def async_runner_hlo(*, n: int, block: int, use_lanes: bool = True,
                             args)
     with jax.default_matmul_precision("highest"):
         return runner.lower(*args).compile().as_text()
+
+
+def cascaded_step_compiled(sharding, *, n_layers: int = 2, batch: int = 8,
+                           seq: int = 512, q: int = 4):
+    """The sync cascaded step of ``Federation.sync_step`` at phi3-mini's
+    published widths over ``n_layers`` layers, jitted as the train driver
+    jits it (``donate_argnums=(0, 1)``), SGD, compiled from shapes placed
+    by ``sharding``."""
+    from repro.configs import VFLConfig, driver_config
+    from repro.core.async_engine import EngineConfig
+    from repro.federation import Federation
+    from repro.models import common
+    from repro.optim import make_schedule, sgd
+
+    cfg = driver_config("phi3-mini-3.8b", use_reduced=False,
+                        n_layers=n_layers)
+    assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab_size) == (
+        3072, 32, 8192, 32064)
+    fed = Federation.build(cfg, VFLConfig(zoo_queries=q),
+                           EngineConfig(method="cascaded", batch_size=batch),
+                           seq_len=seq)
+    opt = sgd(make_schedule("constant", 0.01))
+
+    def place(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+    params = jax.tree.map(place, common.abstract(fed.model.param_specs))
+    opt_state = jax.tree.map(place, jax.eval_shape(opt.init, params))
+    tokens = place(jax.ShapeDtypeStruct((batch, seq), jnp.int32))
+    key = place(jax.eval_shape(lambda: jax.random.key(0)))
+    return jax.jit(fed.sync_step(opt), donate_argnums=(0, 1)).lower(
+        params, opt_state, {"tokens": tokens, "labels": tokens},
+        key).compile()

@@ -294,7 +294,8 @@ def test_cascaded_step_scopes():
         params, opt.init(params), batch, jax.random.key(1)
     ).compile().as_text())
     for scope in ("cascade.client_lanes", "cascade.server_fwd_bwd",
-                  "cascade.client_update", "cascade.server_update"):
+                  "cascade.server_lanes", "cascade.client_update",
+                  "cascade.server_update"):
         assert scope in text, scope
 
 

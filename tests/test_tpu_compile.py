@@ -82,39 +82,33 @@ def test_stacked_kernels_compile(one_chip, fused, M, K, N, dtype):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_cascaded_train_step_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def cascaded_step(one_chip):
     """The sync cascaded step at phi3-mini's published widths, 2 layers,
-    batch 8 x seq 512, q = 4: the program fits one v5e's memory."""
-    from repro.configs import VFLConfig, driver_config
-    from repro.core.async_engine import EngineConfig
-    from repro.federation import Federation
-    from repro.models import common
-    from repro.optim import make_schedule, sgd
+    batch 8 x seq 512, q = 4, compiled once for both tests below."""
+    import _hlo
+    return _hlo.cascaded_step_compiled(one_chip, n_layers=2, batch=8,
+                                       seq=512, q=4)
 
-    batch, seq = 8, 512
-    cfg = driver_config("phi3-mini-3.8b", use_reduced=False, n_layers=2)
-    assert (cfg.d_model, cfg.n_heads, cfg.d_ff, cfg.vocab_size) == (
-        3072, 32, 8192, 32064)
-    fed = Federation.build(cfg, VFLConfig(zoo_queries=4),
-                           EngineConfig(method="cascaded", batch_size=batch),
-                           seq_len=seq)
-    opt = sgd(make_schedule("constant", 0.01))
-    step = fed.sync_step(opt)
 
-    def place(x):
-        return _on(one_chip, x.shape, x.dtype)
-
-    params = jax.tree.map(place, common.abstract(fed.model.param_specs))
-    opt_state = jax.tree.map(place, jax.eval_shape(opt.init, params))
-    tokens = _on(one_chip, (batch, seq), jnp.int32)
-    key = place(jax.eval_shape(lambda: jax.random.key(0)))
-    compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
-        params, opt_state, {"tokens": tokens, "labels": tokens},
-        key).compile()
-    mem = compiled.memory_analysis()
+def test_cascaded_train_step_fits_one_chip(cascaded_step):
+    """The program fits one v5e's memory."""
+    mem = cascaded_step.memory_analysis()
     peak = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
     assert 0 < peak < V5E_HBM_BYTES, peak
+
+
+def test_cascaded_step_backpropagates_the_clean_lane_only(cascaded_step):
+    """The server's backward runs over the clean lane alone: the q = 4
+    perturbed lanes are one forward pass over ``[4,8,512,*]``, and no
+    array carries all 1+q = 5 lanes of the batch. Such an array means a
+    backward, and its saved activations, over every lane: 2.3x the
+    step's FLOPs at this size."""
+    import re
+    hlo = cascaded_step.as_text()
+    assert re.search(r"\[4,8,512[,\]]", hlo)
+    assert re.findall(r"\w+\[5,8,512[,\]][^ ]*", hlo) == []
 
 
 @pytest.mark.parametrize("block", [1, 4])

@@ -10,6 +10,7 @@ from repro.configs import VFLConfig
 from repro.configs.paper_mlp import PaperMLPConfig
 from repro.core import async_engine, cascade, zoo
 from repro.core.adapters import mlp_adapter, tabular_adapter
+from repro.core.partition import merge_params, split_params
 from repro.data import make_classification, vertical_partition
 from repro.kernels.zoo_dual_matmul.ops import zoo_dual_matmul_stacked
 from repro.kernels.zoo_dual_matmul.ref import zoo_dual_matmul_stacked_ref
@@ -121,6 +122,80 @@ def test_fused_cascade_step_matches_unrolled_oracle(q):
                                float(o_u.loss_perturbed), rtol=1e-5)
     np.testing.assert_allclose(float(o_f.grad_client_norm),
                                float(o_u.grad_client_norm), rtol=5e-3)
+
+
+class _GradsOut:
+    """An optimizer whose update returns the gradients it is given, so a
+    step's new params are its merged gradient."""
+    def init(self, params):
+        return None
+
+    def update(self, grads, state, params):
+        return grads, state
+
+
+class _Recording:
+    """A DP transport that keeps what crosses its downlink, both ways."""
+    def __init__(self, inner):
+        self.noise, self.inner = inner.noise, inner
+        self.sent, self.recv = [], []
+
+    def downlink(self, losses, key):
+        out = self.inner.downlink(losses, key)
+        self.sent.append(losses)
+        self.recv.append(out)
+        return out
+
+
+@pytest.mark.parametrize("q", [1, 4])
+def test_fused_cascade_server_grad_is_the_clean_lane_grad(q):
+    """Eq. 4 on the fused path: the server's gradient is ``jax.grad`` of
+    the clean lane's loss alone, in float32."""
+    params, batch, loss_fn = make_toy()
+    vfl = VFLConfig(mu=1e-3, zoo_queries=q, lr_server=0.05, lr_client=0.05)
+    step = jax.jit(cascade.make_cascaded_step(loss_fn, CLIENT_KEYS, vfl,
+                                              _GradsOut()))
+    grads, _, out = step(params, None, batch, jax.random.key(5))
+    client, server = split_params(params, CLIENT_KEYS)
+    loss, want = jax.value_and_grad(
+        lambda s: loss_fn(merge_params(client, s), batch)[0])(server)
+    tree_allclose(split_params(grads, CLIENT_KEYS)[1], want,
+                  rtol=1e-6, atol=1e-8)
+    np.testing.assert_allclose(float(out.loss), float(loss), rtol=1e-6)
+
+
+def test_fused_cascade_downlink_carries_clean_then_lanes():
+    """The 1+q losses handed to a DP transport's downlink are the clean
+    lane's, then lanes 1..q in draw order; the client's Eq. 3 update is
+    built from what the downlink returns, noise and all."""
+    from repro.core.privacy import GaussianLossChannel
+    from repro.federation.transport import Transport
+    params, batch, loss_fn = make_toy()
+    q, mu, key = 4, 1e-3, jax.random.key(6)
+    vfl = VFLConfig(mu=mu, zoo_queries=q, lr_server=0.05, lr_client=0.05)
+    wire = _Recording(Transport("cascaded", noise=GaussianLossChannel(
+        clip=5.0, epsilon=0.5, delta=1e-5)))
+    step = cascade.make_cascaded_step(loss_fn, CLIENT_KEYS, vfl,
+                                      _GradsOut(), transport=wire)
+    grads, _, out = step(params, None, batch, key)
+    (sent,), (recv,) = wire.sent, wire.recv
+    client, server = split_params(params, CLIENT_KEYS)
+    u_stack, d_eff = zoo.sample_directions(key, client, q, vfl.zoo_dist)
+    want = [loss_fn(params, batch)[0]] + [
+        loss_fn(merge_params(zoo.perturb(
+            client, jax.tree.map(lambda u: u[i], u_stack), mu), server),
+            batch)[0] for i in range(q)]
+    assert sent.shape == (1 + q,)
+    np.testing.assert_allclose(np.asarray(sent), np.asarray(want),
+                               rtol=1e-6)
+    assert not np.allclose(np.asarray(recv), np.asarray(sent))
+    g_client = zoo.grad_from_losses(u_stack, recv[1:], recv[0], mu,
+                                    zoo.phi_factor(vfl.zoo_dist, d_eff))
+    tree_allclose(split_params(grads, CLIENT_KEYS)[0], g_client,
+                  rtol=1e-6, atol=1e-8)
+    np.testing.assert_allclose(float(out.loss), float(sent[0]), rtol=0)
+    np.testing.assert_allclose(float(out.loss_perturbed), float(sent[1]),
+                               rtol=0)
 
 
 def test_full_zoo_step_vectorized_matches_oracle():
